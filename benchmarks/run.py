@@ -57,8 +57,8 @@ Env knobs: HELIX_BENCH_ITERS (default 10), HELIX_BENCH_WORKFLOWS (csv list),
 HELIX_BENCH_PAR_WORKERS (worker-pool width for the pipelined engine),
 HELIX_BENCH_SWEEP_VARIANTS (sweep arms, default 4), HELIX_BENCH_SWEEP_SCALE
 (input-size scale for the sweep bench, default 1 — CI smoke uses ~0.05),
-HELIX_BENCH_LM_STEPS / HELIX_BENCH_LM_DM (bench_tier LM train steps and
-d_model, defaults 4 / 128), HELIX_BENCH_TENANT_FAMILIES
+HELIX_BENCH_LM_STEPS (bench_tier LM train steps, default 4),
+HELIX_BENCH_TENANT_FAMILIES
 (bench_multitenant workflow families, default 6).
 """
 from __future__ import annotations
@@ -84,6 +84,7 @@ sys.path.insert(0, os.path.dirname(__file__))
 from repro.core import IterativeSession, Policy  # noqa: E402
 from repro.core.dag import DAG, Node             # noqa: E402
 from repro.core import oep                       # noqa: E402
+from repro.launch.cache import init_compile_cache  # noqa: E402
 
 import workflows as W                            # noqa: E402
 
@@ -781,8 +782,7 @@ def bench_tier() -> None:
     from repro.core.config import StoreConfig
 
     steps = int(os.environ.get("HELIX_BENCH_LM_STEPS", "4"))
-    d_model = int(os.environ.get("HELIX_BENCH_LM_DM", "128"))
-    k = dataclasses.replace(W.LMKnobs(), steps=steps, d_model=d_model)
+    k = dataclasses.replace(W.LMKnobs(), steps=steps)
 
     workdir = os.path.join(ROOT, "lm_tier")
     shutil.rmtree(workdir, ignore_errors=True)
@@ -1035,6 +1035,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     if len(sys.argv) > 1:     # run the named benches only
         for bench_name in sys.argv[1:]:
             fn = globals().get(bench_name)

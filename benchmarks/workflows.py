@@ -14,6 +14,7 @@ as in KeystoneML's MNIST pipeline).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import io
 from typing import Callable
 
@@ -22,8 +23,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import configs
 from repro.core import Workflow
 from repro.data import synth, tabular
+from repro.launch import shapes
+from repro.launch.mesh import make_local_mesh
 from repro.models.config import ArchConfig
 from repro.train import steps as train_steps
 
@@ -597,23 +601,23 @@ def mutate_mnist(k: MNISTKnobs, kind: str, rng) -> MNISTKnobs:
 
 
 # ---------------------------------------------------------------------------
-# 5. LM training (small transformer; large pytree materializations)
+# 5. LM training (a model-zoo config; large pytree materializations)
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class LMKnobs:
-    """A small-config LM training loop on the model zoo's dense family.
+    """LM training on a config from ``repro.configs``, cut to ``n_layers``.
 
+    ``reduced`` swaps in the config's CPU-sized same-family widths
+    (``configs.reduced``); ``reduced=False`` trains at published widths.
     Unlike the four survey workflows, the expensive reusable artifacts
     here are *pytrees of jax arrays* (a TrainState of params + AdamW
     moments), which is what the store's memory tier exists to serve
     zero-copy: a warm rerun should replay the trained state from host
     RAM without touching a single ``.npy``."""
 
+    arch: str = "internlm2-1.8b"
+    reduced: bool = True
     n_layers: int = 2
-    d_model: int = 128
-    n_heads: int = 4
-    d_ff: int = 512
-    vocab: int = 512
     seq_len: int = 64
     batch: int = 8
     steps: int = 4                # train batches (halving resource, LI)
@@ -622,54 +626,119 @@ class LMKnobs:
     report_percentiles: bool = False   # PPR knob (loss-report formatting)
 
 
-def _lm_arch(k: LMKnobs) -> ArchConfig:
-    # attn_impl="chunked" — pure-jnp attention; the Pallas flash kernel
-    # needs a TPU and this workflow must run on the CI's CPU.
-    return ArchConfig(
-        name="bench-lm", family="dense", num_layers=k.n_layers,
-        d_model=k.d_model, num_heads=k.n_heads, num_kv_heads=k.n_heads,
-        d_ff=k.d_ff, vocab_size=k.vocab, attn_impl="chunked")
+def lm_arch(k: LMKnobs) -> ArchConfig:
+    # The config's attn_impl stays "chunked" (XLA): the Pallas flash
+    # kernel is forward-only, and this workflow trains.
+    cfg = configs.get(k.arch)
+    if k.reduced:
+        cfg = configs.reduced(cfg)
+    return dataclasses.replace(cfg, num_layers=k.n_layers)
 
 
-def build_lm(k: LMKnobs) -> Workflow:
-    cfg = _lm_arch(k)
+class _LMPrograms:
+    """The LM workflow's jitted programs, built once per configuration and
+    mesh (a fresh ``jax.jit`` per node call retraces), with the TrainState
+    sharded on ``mesh`` under the config's train ruleset."""
+
+    def __init__(self, cfg: ArchConfig, peak_lr: float, total_steps: int,
+                 mesh):
+        self.mesh = mesh
+        self.state, self.batch_axes = shapes.train_shardings(cfg, mesh)
+        self.init = jax.jit(
+            functools.partial(train_steps.init_train_state, cfg),
+            out_shardings=self.state)
+        self.train = jax.jit(
+            functools.partial(train_steps.train_step, cfg, peak_lr=peak_lr,
+                              warmup_steps=2, total_steps=total_steps,
+                              clip_norm=1.0),
+            out_shardings=(self.state, None), donate_argnums=0)
+        self.eval = jax.jit(lambda params, tokens: train_steps.loss_fn(
+            cfg, params, {"tokens": tokens})[0])
+
+    def put_tokens(self, tokens):
+        return jax.device_put(tokens, shapes.batch_sharding(
+            self.mesh, tokens.shape, self.batch_axes))
+
+    def trained_placement(self):
+        """``sharding_for_leaf`` for the train node's value
+        ``{"losses": host array, "state": TrainState}``: the state's
+        leaves onto their training placement, the losses left on host."""
+        leaves = jax.tree_util.tree_leaves(
+            {"losses": None, "state": self.state},
+            is_leaf=lambda x: x is None)
+        return lambda i, shape, dtype: leaves[i]
+
+
+@functools.lru_cache(maxsize=16)
+def _lm_programs(cfg: ArchConfig, peak_lr: float, total_steps: int,
+                 mesh) -> _LMPrograms:
+    return _LMPrograms(cfg, peak_lr, total_steps, mesh)
+
+
+def build_lm(k: LMKnobs, mesh=None) -> Workflow:
+    """tokens → initState → train → evalLoss. ``mesh`` (default: every
+    local device, ``make_local_mesh()``) holds the TrainState and the
+    batches; a stored TrainState reloads onto it."""
+    cfg = lm_arch(k)
+    pr = _lm_programs(cfg, k.peak_lr, max(k.steps, 3),
+                      mesh if mesh is not None else make_local_mesh())
     wf = Workflow("lm")
 
     def make_tokens():
         rng = np.random.default_rng(k.seed + 101)
         # steps train batches + 1 held-out eval batch
-        return rng.integers(0, k.vocab, (k.steps + 1, k.batch, k.seq_len),
+        return rng.integers(0, cfg.vocab_size,
+                            (k.steps + 1, k.batch, k.seq_len),
                             dtype=np.int32)
 
     tokens = wf.source("tokens", make_tokens,
-                       config=("tok", k.vocab, k.seq_len, k.batch, k.steps,
-                               k.seed))
-    state0 = wf.source(
-        "initState",
-        lambda: train_steps.init_train_state(cfg, jax.random.PRNGKey(k.seed)),
-        config=("init", k.n_layers, k.d_model, k.n_heads, k.d_ff, k.vocab,
-                k.seed))
+                       config=("tok", cfg.vocab_size, k.seq_len, k.batch,
+                               k.steps, k.seed))
+
+    def init_state():
+        with pr.mesh:
+            return pr.init(jax.random.PRNGKey(k.seed))
+
+    state0 = wf.source("initState", init_state, config=("init", cfg, k.seed))
 
     def train(tok, state):
-        step = jax.jit(lambda s, b: train_steps.train_step(
-            cfg, s, b, peak_lr=k.peak_lr, warmup_steps=2,
-            total_steps=max(k.steps, 3), clip_norm=1.0))
         losses = []
-        for i in range(k.steps):
-            state, metrics = step(state, {"tokens": jnp.asarray(tok[i])})
-            losses.append(float(metrics["loss"]))
+        with pr.mesh:
+            # A copy the steps may donate: the node's input stays whole
+            # for the store's writer and the executor. Without donation,
+            # a step would hold the input, its predecessor's output and
+            # its own — three TrainStates.
+            state = jax.device_put(state, pr.state, may_alias=False)
+            for i in range(k.steps):
+                state, metrics = pr.train(state, {"tokens":
+                                                  pr.put_tokens(tok[i])})
+                losses.append(float(metrics["loss"]))
         return {"state": state, "losses": np.asarray(losses, np.float64)}
 
     trained = wf.learner(
         "train", train, [tokens, state0],
-        config=("train", k.n_layers, k.d_model, k.n_heads, k.d_ff, k.vocab,
-                k.seq_len, k.batch, k.steps, k.peak_lr))
+        config=("train", cfg, k.seq_len, k.batch, k.steps, k.peak_lr))
+    wf.load_shardings["train"] = pr.trained_placement()
 
     def eval_loss(tok, tr):
-        loss, _ = train_steps.loss_fn(
-            cfg, tr["state"].params, {"tokens": jnp.asarray(tok[-1])})
+        params = tr["state"].params
+        with pr.mesh:
+            loss = pr.eval(params, pr.put_tokens(tok[-1]))
+        leaves = jax.tree_util.tree_leaves(params)
         out = {"eval_loss": float(loss),
-               "train_losses": tr["losses"].tolist()}
+               "train_losses": tr["losses"].tolist(),
+               # Where the evaluated state lives: a state reloaded onto
+               # one device (or left on host) shows fewer devices than
+               # the mesh holds.
+               "placement": {
+                   "min_devices": min(
+                       len(x.sharding.device_set)
+                       if isinstance(x, jax.Array) else 0 for x in leaves),
+                   "split_leaves": sum(
+                       isinstance(x, jax.Array)
+                       and not x.sharding.is_fully_replicated
+                       for x in leaves),
+                   "leaves": len(leaves)}}
         if k.report_percentiles:
             qs = np.percentile(tr["losses"], [0, 50, 100])
             out["loss_percentiles"] = {"p0": float(qs[0]),

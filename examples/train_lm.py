@@ -1,6 +1,7 @@
 """End-to-end driver: train a ~100M-parameter LM with segment checkpointing.
 
-    # real ~100M model (slow on CPU; the real target is a TPU pod):
+    # real ~100M model, on a TPU (one v5e chip holds it; the mesh spans
+    # every local chip):
     PYTHONPATH=src python examples/train_lm.py --steps 300
 
     # CPU-sized demo of the same code path (~15M params):

@@ -271,7 +271,10 @@ class IterativeSession:
         executor force-persists those on lease-compute). ``cancel``
         forwards a cooperative cancel flag to the executor (checked
         between nodes; the run raises
-        :class:`~repro.core.executor.JobCancelled` after settling)."""
+        :class:`~repro.core.executor.JobCancelled` after settling).
+        ``load_shardings`` defaults to the workflow's own."""
+        if load_shardings is None:
+            load_shardings = workflow.load_shardings
         dag = workflow.build()
         sigs = compute_signatures(dag, nonces=nonces)
         ev_before = (self.evictor.stats.snapshot()

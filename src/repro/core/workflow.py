@@ -52,6 +52,10 @@ class Workflow:
         self.name = name
         self._nodes: list[Node] = []
         self._outputs: set[str] = set()
+        # {node name: sharding_for_leaf(i, shape, dtype)} — where a loaded
+        # value's array leaves are placed (see Store.load); a session run
+        # without its own ``load_shardings`` uses these.
+        self.load_shardings: dict[str, Callable] = {}
 
     # -- generic declaration -----------------------------------------------------
     def node(self, name: str, fn: Callable, inputs: Iterable = (),

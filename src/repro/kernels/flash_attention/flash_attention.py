@@ -2,12 +2,14 @@
 
 Tiling: grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is
 the innermost, *sequential* grid axis — the fp32 accumulator, running max
-and running sum live in VMEM scratch across kv iterations. Q/K/V blocks are
-(bq × head_dim) / (bk × head_dim) VMEM tiles (128-aligned for the MXU).
+and running sum live in VMEM scratch across kv iterations. Arrays are
+head-major, (B, H, S, D), with batch and head squeezed out of each block,
+so every block is a (bq × head_dim) / (bk × head_dim) tile whose last two
+dims satisfy the TPU's (8, 128) tiling rule (head_dim is the full dim).
 
 Supports: causal masking, sliding windows (per-call static window size),
-GQA (q head h reads kv head h // group), and a traced per-call q position
-offset (prefill continuation) via scalar prefetch.
+GQA (q head h reads kv head h // group), and a traced per-sequence q
+position offset (prefill continuation) via scalar prefetch.
 
 Masked-out kv blocks are predicated away with ``pl.when`` — for causal
 training that halves the work; for a 1024-window gemma3 layer the cost is
@@ -21,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -40,7 +40,7 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qoff = qoff_ref[0]
+    qoff = qoff_ref[pl.program_id(0)]
     q_start = qoff + iq * bq
     k_start = ikv * bk
     # Block-level predication: skip kv blocks fully outside the mask.
@@ -52,9 +52,9 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(need)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)            # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
+        q = q_ref[...].astype(jnp.float32)                   # (bq, d)
+        k = k_ref[...].astype(jnp.float32)                   # (bk, d)
+        v = v_ref[...].astype(jnp.float32)                   # (bk, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (bq, bk)
@@ -67,14 +67,14 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref,
             mask &= (q_pos - k_pos) < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                  # (bq, 1)
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -83,7 +83,7 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ikv == nkv - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -91,7 +91,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool, window: int,
                            bq: int, bk: int,
                            interpret: bool) -> jax.Array:
-    """q: (B, Sq, H, D); k, v: (B, Sk, KV, D); q_offset: () int32.
+    """q: (B, Sq, H, D); k, v: (B, Sk, KV, D); q_offset: () or (B,) int32.
 
     window <= 0 means global. Returns (B, Sq, H, D) in q.dtype.
     """
@@ -100,36 +100,36 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     g = h // kvh
     assert sq % bq == 0 and sk % bk == 0, (sq, bq, sk, bk)
     nq, nkv = sq // bq, sk // bk
-    grid = (b, h, nq, nkv)
+    qoff = jnp.broadcast_to(
+        jnp.asarray(q_offset, jnp.int32).reshape(-1), (b,))
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))   # head-major
 
     kernel = functools.partial(
         _kernel, causal=causal, window=window, bq=bq, bk=bk, nkv=nkv,
         scale=d ** -0.5)
+    sqz = pl.Squeezed()
+    q_spec = pl.BlockSpec((sqz, sqz, bq, d),
+                          lambda bi, hi, qi, ki, qoff: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec((sqz, sqz, bk, d),
+                           lambda bi, hi, qi, ki, qoff: (bi, hi // g, ki, 0))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, 1, d),
-                             lambda bi, hi, qi, ki, qoff: (bi, qi, hi, 0)),
-                pl.BlockSpec((1, bk, 1, d),
-                             lambda bi, hi, qi, ki, qoff: (bi, ki, hi // g, 0)),
-                pl.BlockSpec((1, bk, 1, d),
-                             lambda bi, hi, qi, ki, qoff: (bi, ki, hi // g, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, bq, 1, d), lambda bi, hi, qi, ki, qoff: (bi, qi, hi, 0)),
+            grid=(b, h, nq, nkv),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((bq, d), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(q_offset, jnp.int32).reshape(1), q, k, v)
+    )(qoff, qt, kt, vt)
+    return jnp.swapaxes(out, 1, 2)

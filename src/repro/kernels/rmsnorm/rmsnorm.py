@@ -9,8 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -34,7 +33,7 @@ def rmsnorm_pallas(x: jax.Array, w: jax.Array, *, eps: float,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from .ssd import ssd_chunk_pallas
+from .. import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -32,9 +33,8 @@ def ssd(x, dt, a, B, C, *, chunk: int = 128, h0=None):
     da = (dt * a).reshape(bsz, nc, L, H)
     cs = jnp.cumsum(da, axis=2).reshape(bsz, S, H)       # within-chunk
 
-    interpret = jax.default_backend() != "tpu"
     y_intra, states = ssd_chunk_pallas(
-        x, dt, cs, B, C, chunk=L, interpret=interpret)
+        x, dt, cs, B, C, chunk=L, interpret=interpret_mode())
 
     # inter-chunk scan over boundary states
     seg = jnp.exp(cs.reshape(bsz, nc, L, H)[:, :, -1, :])  # (b,nc,H)

@@ -1,20 +1,17 @@
+import argparse
+import dataclasses
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init). Everything below is ordinary.
-import argparse      # noqa: E402
-import dataclasses   # noqa: E402
-import json          # noqa: E402
-import re            # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import re
+import time
+import traceback
 
-import jax           # noqa: E402
-import numpy as np   # noqa: E402
+import jax
+import numpy as np
 
-from .. import configs                      # noqa: E402
-from ..launch import shapes as shapes_lib   # noqa: E402
-from ..launch.mesh import make_production_mesh  # noqa: E402
+from .. import configs
+from ..launch import shapes as shapes_lib
+from ..launch.mesh import make_production_mesh
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -241,6 +238,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main() -> None:
+    # 512 placeholder CPU devices. JAX fixes the device count at its first
+    # backend use, so this must precede any computation — and stays out of
+    # import time, where it would change the device set of every importer.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -7,8 +7,6 @@ see one CPU device while the dry-run sees 512 placeholders.
 from __future__ import annotations
 
 import jax
-
-
 import numpy as np
 
 
@@ -22,10 +20,14 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices, have {len(devices)} — run via "
             f"launch/dryrun.py which sets xla_force_host_platform_device_count")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_local_mesh():
-    """Whatever devices exist locally (1 CPU in tests), as (data, model)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+def make_local_mesh(devices=None):
+    """``devices`` (default: whatever exists locally, 1 CPU in tests) as a
+    (data, model) mesh."""
+    devices = jax.devices() if devices is None else list(devices)
+    return jax.make_mesh((len(devices), 1), ("data", "model"),
+                         devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

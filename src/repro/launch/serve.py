@@ -17,10 +17,12 @@ from .. import configs
 from ..data import synth
 from ..models import registry
 from ..train import steps
+from .cache import init_compile_cache
 from .mesh import make_local_mesh
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -77,7 +79,7 @@ def main() -> None:
     print(f"arch={cfg.name} batch={args.batch} prompt={args.prompt_len} "
           f"gen={args.gen_tokens}")
     print(f"prefill {t_prefill*1e3:.1f} ms; decode {t_decode*1e3:.1f} ms "
-          f"({tok_s:.1f} tok/s)")
+          f"({tok_s:.1f} tok/s) on {jax.devices()[0].device_kind}")
     print("first sequence:", gen[0][:16].tolist())
 
 

@@ -126,6 +126,13 @@ def _named(mesh, shape, entries):
     return NamedSharding(mesh, _filter_spec(shape, entries, mesh))
 
 
+def batch_sharding(mesh, shape: tuple, bd: tuple = ("pod", "data")
+                   ) -> NamedSharding:
+    """Leading (batch) dim over the mesh axes ``bd`` that exist and
+    divide it; every other dim replicated."""
+    return _named(mesh, shape, [bd] + [None] * (len(shape) - 1))
+
+
 def _batch_shardings(cfg: ArchConfig, sh: ShapeSpec, mesh, batch_sds: dict,
                      bd: tuple = ("pod", "data")) -> dict:
     out = {}
@@ -133,16 +140,30 @@ def _batch_shardings(cfg: ArchConfig, sh: ShapeSpec, mesh, batch_sds: dict,
         if k == "mrope_positions":
             out[k] = _named(mesh, sds.shape, [None, bd, None])
         else:
-            out[k] = _named(mesh, sds.shape,
-                            [bd] + [None] * (len(sds.shape) - 1))
+            out[k] = batch_sharding(mesh, sds.shape, bd)
     return out
 
 
-def _params_shardings(cfg: ArchConfig, mesh, params_sds, ruleset: dict):
+def _params_shardings(cfg: ArchConfig, mesh, ruleset: dict):
     specs = param_specs(registry.param_defs(cfg), mesh, ruleset)
     return jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), specs,
         is_leaf=lambda s: isinstance(s, PartitionSpec))
+
+
+def train_shardings(cfg: ArchConfig, mesh, ruleset_name: str | None = None
+                    ) -> tuple[steps.TrainState, tuple]:
+    """TrainState shardings and the batch's mesh axes for training ``cfg``
+    on ``mesh`` under ``ruleset_name`` (default: the config's
+    ``train_ruleset``, else ``train_2d``). Optimizer moments shard like
+    the params; the step counter is replicated."""
+    rname = ruleset_name or cfg.train_ruleset or "train_2d"
+    pshard = _params_shardings(cfg, mesh, rules_lib.RULESETS[rname])
+    state = steps.TrainState(
+        params=pshard,
+        opt=steps.adamw.AdamWState(
+            m=pshard, v=pshard, step=NamedSharding(mesh, PartitionSpec())))
+    return state, rules_lib.BATCH_AXES_BY_RULESET.get(rname, ("pod", "data"))
 
 
 def _cache_shardings(cfg: ArchConfig, sh: ShapeSpec, mesh, cache_sds):
@@ -192,16 +213,8 @@ def build_step(cfg: ArchConfig, shape_name: str, mesh,
     sh = SHAPES[shape_name]
     args = input_specs(cfg, shape_name)
     if sh.kind == "train":
-        rname = ruleset_name or cfg.train_ruleset or "train_2d"
-        ruleset = rules_lib.RULESETS[rname]
-        bd = rules_lib.BATCH_AXES_BY_RULESET.get(rname, ("pod", "data"))
-        state_sds, batch_sds = args
-        pshard = _params_shardings(cfg, mesh, state_sds.params, ruleset)
-        state_shard = steps.TrainState(
-            params=pshard,
-            opt=steps.adamw.AdamWState(
-                m=pshard, v=pshard,
-                step=NamedSharding(mesh, PartitionSpec())))
+        state_shard, bd = train_shardings(cfg, mesh, ruleset_name)
+        _, batch_sds = args
         in_shardings = (state_shard,
                         _batch_shardings(cfg, sh, mesh, batch_sds, bd=bd))
         out_shardings = (state_shard, None)
@@ -214,7 +227,7 @@ def build_step(cfg: ArchConfig, shape_name: str, mesh,
     ruleset = rules_lib.RULESETS[ruleset_name or "serve"]
     if sh.kind == "prefill":
         params_sds, batch_sds = args
-        pshard = _params_shardings(cfg, mesh, params_sds, ruleset)
+        pshard = _params_shardings(cfg, mesh, ruleset)
         in_shardings = (pshard, _batch_shardings(cfg, sh, mesh, batch_sds))
         cache_sds = jax.eval_shape(
             lambda p, b: steps.prefill_step(cfg, p, b, max_len=sh.seq)[1],
@@ -224,7 +237,7 @@ def build_step(cfg: ArchConfig, shape_name: str, mesh,
         return fn, args, in_shardings, out_shardings, ()
     # decode
     params_sds, token_sds, cache_sds = args
-    pshard = _params_shardings(cfg, mesh, params_sds, ruleset)
+    pshard = _params_shardings(cfg, mesh, ruleset)
     cshard = _cache_shardings(cfg, sh, mesh, cache_sds)
     tshard = _named(mesh, token_sds.shape, [("pod", "data"), None])
     in_shardings = (pshard, tshard, cshard)

@@ -24,11 +24,10 @@ from ..checkpoint import CheckpointManager
 from ..core.store import Store
 from ..data import synth
 from ..data.pipeline import TokenBatcher
-from ..models.params import param_specs
-from ..models import registry
-from ..sharding import rules as rules_lib
 from ..train import steps
+from .cache import init_compile_cache
 from .mesh import make_local_mesh, make_production_mesh
+from .shapes import train_shardings
 
 
 class Watchdog:
@@ -51,6 +50,7 @@ class Watchdog:
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="helix100m")
     ap.add_argument("--reduced", action="store_true")
@@ -73,7 +73,8 @@ def main() -> None:
     mesh = (make_production_mesh() if args.production_mesh
             else make_local_mesh())
     print(f"arch={cfg.name} params≈{cfg.param_count()/1e6:.1f}M "
-          f"mesh={dict(mesh.shape)} devices={len(jax.devices())}")
+          f"mesh={dict(mesh.shape)} devices={len(jax.devices())} "
+          f"device={jax.devices()[0].device_kind}")
 
     tokens = synth.lm_tokens(args.seed, max(2_000_000,
                                             args.batch * (args.seq + 1) * 4),
@@ -83,37 +84,20 @@ def main() -> None:
     store = Store(f"{args.workdir}/store")
     ckpt = CheckpointManager(store, run_name=f"{cfg.name}-s{args.seed}")
 
-    specs = param_specs(registry.param_defs(cfg), mesh,
-                        rules_lib.TRAIN_2D)
-    pshard = jax.tree_util.tree_map(
-        lambda s: jax.sharding.NamedSharding(mesh, s), specs,
-        is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    state_shard, _ = train_shardings(cfg, mesh, "train_2d")
 
     with mesh:
         start_step = 0
         if args.resume:
             latest = ckpt.latest_step()
             if latest is not None:
-                def shard_for(i, shape, dtype, _fs=None):
-                    return None   # restore to host, device_put below
-                state = ckpt.restore(latest)
-                state = jax.device_put(state, steps.TrainState(
-                    params=pshard,
-                    opt=steps.adamw.AdamWState(
-                        m=pshard, v=pshard,
-                        step=jax.sharding.NamedSharding(
-                            mesh, jax.sharding.PartitionSpec()))))
+                state = jax.device_put(ckpt.restore(latest), state_shard)
                 start_step = latest
                 print(f"resumed from step {latest} "
                       f"(elastic restore onto {dict(mesh.shape)})")
         if start_step == 0:
             state = steps.init_train_state(cfg, jax.random.PRNGKey(args.seed))
-            state = jax.device_put(state, steps.TrainState(
-                params=pshard,
-                opt=steps.adamw.AdamWState(
-                    m=pshard, v=pshard,
-                    step=jax.sharding.NamedSharding(
-                        mesh, jax.sharding.PartitionSpec()))))
+            state = jax.device_put(state, state_shard)
 
         jstep = jax.jit(
             lambda st, b: steps.train_step(

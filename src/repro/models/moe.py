@@ -57,7 +57,6 @@ def moe_block_sharded(mcfg: MoECfg, p: dict, x: jax.Array
 
     Falls back to the einsum path when no mesh is active (CPU tests).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
     from ..sharding.activation import _active_mesh, batch_axes
 
@@ -91,11 +90,11 @@ def moe_block_sharded(mcfg: MoECfg, p: dict, x: jax.Array
                              "w_down": PS("model", None)}
         p_specs["shared_gate"] = PS(None, None)
     p_in = {k: p[k] for k in p_specs}
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(bd, None, None), p_specs),
         out_specs=(PS(bd, None, None), PS()),
-        check_rep=False,
+        check_vma=False,
     )(x, p_in)
     return out, aux
 
@@ -110,7 +109,6 @@ def moe_block_a2a(mcfg: MoECfg, p: dict, x: jax.Array
     of expert-TP's full psum per layer), expert-computed, and a2a'd back.
     Falls back to expert-TP shard_map when indivisible / no mesh.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
     from ..sharding.activation import _active_mesh, batch_axes
 
@@ -228,11 +226,11 @@ def moe_block_a2a(mcfg: MoECfg, p: dict, x: jax.Array
                              "w_down": PS("model", None)}
         p_specs["shared_gate"] = PS(None, None)
     p_in = {k: p[k] for k in p_specs}
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(bd, None, None), p_specs),
         out_specs=(PS(bd, None, None), PS()),
-        check_rep=False,
+        check_vma=False,
     )(x, p_in)
     return out, aux
 

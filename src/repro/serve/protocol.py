@@ -31,7 +31,9 @@ Responses always carry ``ok`` (bool); failures carry ``error`` (str).
 with a job summary (status, timings, execution counts, JSON-coerced
 outputs — see :func:`jsonable`); with ``detail: true`` the summary's
 ``execution`` block also lists ``computed_sigs`` /
-``blind_computed_sigs`` for fleet duplicate-compute accounting. A
+``blind_computed_sigs`` for fleet duplicate-compute accounting and
+``node_states`` / ``node_seconds`` (each node's realized compute /
+load / prune, and its seconds). A
 ``wait`` that times out responds ``ok: false`` with a ``TimeoutError:``
 message. The server retains the last ``max_finished_jobs`` summaries;
 ``forget`` releases one eagerly.

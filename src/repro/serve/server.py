@@ -825,7 +825,10 @@ class SessionServer:
         and the subset of those that were *blind* computes (not the
         planner's deliberate recompute-cheaper-than-load choice) — the
         raw material for transport-agnostic fleet duplicate-compute
-        accounting (see ``SearchReport.wasted_recomputes``)."""
+        accounting (see ``SearchReport.wasted_recomputes``) — and each
+        node's realized state (``node_states``: compute / load / prune,
+        a deduped compute counting as the load it became) and seconds
+        (``node_seconds``)."""
         j = job if isinstance(job, Job) else self._jobs[job]
         out: dict[str, Any] = {
             "job": j.id, "name": j.name, "status": j.status,
@@ -850,6 +853,11 @@ class SessionServer:
                 out["execution"]["blind_computed_sigs"] = sorted(
                     j.report.sigs[n] for n in computed
                     if n not in ex.chose_compute)
+                out["execution"]["node_states"] = {
+                    n: State.LOAD.value if n in ex.deduped else s.value
+                    for n, s in ex.states.items()}
+                out["execution"]["node_seconds"] = {
+                    n: round(t, 6) for n, t in ex.runtime.items()}
             if j.report.evictions:
                 # Fleet evictor-stat deltas over this job's run window
                 # (the evictor is shared, so concurrent jobs' windows

@@ -21,15 +21,3 @@ try:
 except ImportError:
     pass
 
-
-def make_abstract_mesh(axis_sizes, axis_names):
-    """Build a ``jax.sharding.AbstractMesh`` across jax versions.
-
-    jax <= 0.4.35 and >= 0.5 take ``(axis_sizes, axis_names)``; 0.4.36/37
-    take a single ``shape_tuple`` of ``(name, size)`` pairs.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))

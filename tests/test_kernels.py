@@ -116,3 +116,27 @@ def test_rmsnorm_matches_ref(shape, dtype):
     exp = rn_ref.rmsnorm_ref(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=2e-2)
+
+
+# ----------------------------------------------------------- no fallback
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    import repro.kernels as kernels
+    assert kernels.interpret_mode()          # the tests' CPU backend
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    assert not kernels.interpret_mode()
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        kernels.interpret_mode()
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "rmsnorm"])
+def test_untiled_shape_raises_on_tpu(op, monkeypatch):
+    """Where the CPU runs the oracle for an untiled shape, a TPU refuses."""
+    mod = fa_ops if op == "flash_attention" else rn_ops
+    monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    with pytest.raises(ValueError, match="tile"):
+        if op == "flash_attention":
+            q = jnp.zeros((1, 13, 2, 64))          # 13: no 8k block
+            fa_ops.flash_attention(q, q, q, causal=True)
+        else:
+            rn_ops.rmsnorm(jnp.zeros((263, 64)), jnp.ones((64,)))

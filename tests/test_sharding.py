@@ -2,15 +2,14 @@
 batch/cache specs — resolved against an AbstractMesh (no 256 devices needed).
 """
 import jax
-from jax.sharding import PartitionSpec
+from jax.sharding import AbstractMesh, PartitionSpec
 
-from conftest import make_abstract_mesh
 from repro import configs
 from repro.models import registry
 from repro.models.params import P, param_specs
 
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def leaves_with_paths(tree):
@@ -69,7 +68,7 @@ def test_multipod_mesh_resolution():
 
 
 def test_single_device_mesh_all_replicated():
-    mesh1 = make_abstract_mesh((1, 1), ("data", "model"))
+    mesh1 = AbstractMesh((1, 1), ("data", "model"))
     cfg = configs.reduced(configs.get("internlm2-1.8b"))
     specs = leaves_with_paths(param_specs(registry.param_defs(cfg), mesh1))
     assert all(all(e is None for e in tuple(s)) for s in specs.values())
