@@ -1,0 +1,2 @@
+"""Plain float32 references of the benchmark's model families; nothing
+here imports the program."""
