@@ -62,6 +62,7 @@ determinism guarantees above are unchanged, and the mode is off by default.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import heapq
 import threading
@@ -70,6 +71,7 @@ from typing import Any, Callable, Mapping
 
 import jax
 
+from . import spans
 from .chunks import Chunked, tree_concat, tree_stack
 from .dag import DAG, State
 from .eviction import benefit_density
@@ -95,7 +97,6 @@ class ExecutionReport:
     total_seconds: float                 # wall clock of execute()
     outputs: dict[str, Any]
     max_workers: int = 1                 # worker-pool width used
-    peak_resident_loads: int = 0         # prefetch-gate high-water mark
     # COMPUTE-planned nodes whose value was in fact loaded because another
     # session computed the same signature first (in-flight dedupe).
     deduped: dict[str, str] = dataclasses.field(default_factory=dict)
@@ -129,6 +130,13 @@ def _block(value: Any) -> Any:
         if isinstance(leaf, jax.Array):
             leaf.block_until_ready()
     return value
+
+
+def _block_loaded(name: str, value: Any) -> Any:
+    """Wait for a loaded value's copy to the device, which the store
+    only starts and its load seconds leave out."""
+    with spans.span("executor.block", node=name):
+        return _block(value)
 
 
 class _Scheduler:
@@ -205,7 +213,6 @@ class _Scheduler:
 
         # Prefetch gate: loads in flight or resident-and-unconsumed.
         self.resident_loads = 0
-        self.peak_resident_loads = 0
 
         # Chunk-granular plans (chunks.py): COMPUTE nodes with a plan run
         # per-chunk — cached chunks spliced in, missing ones recomputed —
@@ -267,22 +274,21 @@ class _Scheduler:
             self.n_inflight += 1
             if self.states[picked] is State.LOAD:
                 self.resident_loads += 1
-                self.peak_resident_loads = max(self.peak_resident_loads,
-                                               self.resident_loads)
         return picked
 
     # -- node execution (outside the lock) ---------------------------------
     def _run_node(self, name: str) -> tuple[Any, float]:
-        node = self.dag.nodes[name]
-        if self.states[name] is State.LOAD:
-            value, secs = self.store.load(
-                self.sigs[name],
-                sharding_for_leaf=self.load_shardings.get(name))
-            _block(value)
-            return value, secs
-        if self.dedupe and name not in self.dedupe_skip:
-            return self._run_compute_deduped(name, node)
-        return self._run_compute(name, node)
+        with spans.span("executor.node", node=name,
+                        state=self.states[name].value):
+            node = self.dag.nodes[name]
+            if self.states[name] is State.LOAD:
+                value, secs = self.store.load(
+                    self.sigs[name],
+                    sharding_for_leaf=self.load_shardings.get(name))
+                return _block_loaded(name, value), secs
+            if self.dedupe and name not in self.dedupe_skip:
+                return self._run_compute_deduped(name, node)
+            return self._run_compute(name, node)
 
     def _run_compute(self, name: str, node) -> tuple[Any, float]:
         plan = self.chunk_plans.get(name)
@@ -408,7 +414,7 @@ class _Scheduler:
                         sig, sharding_for_leaf=self.load_shardings.get(name))
                 except FileNotFoundError:
                     continue  # raced an eviction — retry
-                _block(value)
+                _block_loaded(name, value)
                 with self.cv:
                     self.deduped[name] = "computed by another session"
                 return value, secs
@@ -580,19 +586,24 @@ class _Scheduler:
                 if name not in self.oos_done:
                     break
             elif name in self.oos_ready:
-                self._decide_locked(name, jobs)
+                with spans.span("executor.decide", node=name) as attrs:
+                    attrs["verdict"] = self._decide_locked(name, jobs)
             else:
                 break
             self.oos_ptr += 1
 
     def _decide_locked(self, name: str,
-                       jobs: list[Callable[[], None]]) -> None:
+                       jobs: list[Callable[[], None]]) -> str:
+        """OMP's verdict on ``name``: ``persisted`` (by the in-flight
+        dedupe), ``stored`` (already), ``materialize``, ``evict`` (to
+        admit) or ``skip``."""
         node = self.dag.nodes[name]
         value = self.cache.get(name)
         if name in self.materialized:
-            pass  # force-persisted by the in-flight dedupe path
+            verdict = "persisted"  # by the in-flight dedupe path
         elif self.store.has(self.sigs[name]):
             self.skipped[name] = "already materialized"
+            verdict = "stored"
         else:
             est_bytes = tree_nbytes(value)
             # Durable-tier price on purpose (no sig): Algorithm 2 is
@@ -616,12 +627,14 @@ class _Scheduler:
                      "load_s_est": est_load}
             sig = self.sigs[name]
             if decision.materialize:
+                verdict = "materialize"
                 self.materialized[name] = decision.reason
                 jobs.append(lambda sig=sig, name=name, value=value,
                             est=est_bytes, extra=extra:
                             self._persist_value(sig, name, value, est,
                                                 extra))
             elif decision.needs_eviction:
+                verdict = "evict"
                 # Evict-to-admit, off the lock. With max_workers=1 the
                 # job runs immediately after this decision (sequential
                 # semantics unchanged); under parallel workers deferred
@@ -647,9 +660,11 @@ class _Scheduler:
                     self._persist_value(sig, name, value, est, extra)
                 jobs.append(job)
             else:
+                verdict = "skip"
                 self.skipped[name] = decision.reason
         if not node.is_output:
             self.cache.pop(name, None)  # eager eviction (§5.4 cache pruning)
+        return verdict
 
     # -- worker loop -------------------------------------------------------
     def _worker(self) -> None:
@@ -728,9 +743,10 @@ class _Scheduler:
             # n_workers-1 extras are borrowed from the shared pool.
             self.worker_pool.run(self._worker, n_workers)
         else:
-            threads = [threading.Thread(target=self._worker,
-                                        name=f"helix-exec-{i}", daemon=True)
-                       for i in range(n_workers)]
+            threads = [threading.Thread(
+                target=contextvars.copy_context().run, args=(self._worker,),
+                name=f"helix-exec-{i}", daemon=True)
+                for i in range(n_workers)]
             for t in threads:
                 t.start()
             for t in threads:
@@ -802,30 +818,30 @@ def execute(dag: DAG,
     the planned nodes: cached chunks are spliced from the store and only
     missing ones recomputed (see chunks.py)."""
     t_start = time.perf_counter()
-    sched = _Scheduler(dag, sigs, states, store, materializer,
-                       load_shardings, async_materialization,
-                       max_workers, prefetch_depth,
-                       dedupe_inflight=dedupe_inflight,
-                       dedupe_wait_seconds=dedupe_wait_seconds,
-                       share_sigs=share_sigs,
-                       dedupe_skip=dedupe_skip,
-                       worker_pool=worker_pool,
-                       cancel=cancel,
-                       chunk_plans=chunk_plans)
-    sched.run()
-    # Outputs are always the logical values: the chunk partitioning is an
-    # executor/store-internal carrier, invisible to session callers.
-    outputs = {n: (v.assemble() if isinstance(v, Chunked) else v)
-               for n, v in ((n, sched.cache[n]) for n in dag.outputs()
-                            if n in sched.cache)}
-    return ExecutionReport(
-        states=dict(states), runtime=sched.runtime,
-        materialized=sched.materialized, skipped_mat=sched.skipped,
-        mat_seconds=sched.mat_seconds,
-        total_seconds=time.perf_counter() - t_start, outputs=outputs,
-        max_workers=sched.max_workers,
-        peak_resident_loads=sched.peak_resident_loads,
-        deduped=sched.deduped,
-        chose_compute=frozenset(dedupe_skip or ()),
-        chunk_computed=sched.chunk_computed,
-        chunk_reused=sched.chunk_reused)
+    with spans.span("executor.run"):
+        sched = _Scheduler(dag, sigs, states, store, materializer,
+                           load_shardings, async_materialization,
+                           max_workers, prefetch_depth,
+                           dedupe_inflight=dedupe_inflight,
+                           dedupe_wait_seconds=dedupe_wait_seconds,
+                           share_sigs=share_sigs,
+                           dedupe_skip=dedupe_skip,
+                           worker_pool=worker_pool,
+                           cancel=cancel,
+                           chunk_plans=chunk_plans)
+        sched.run()
+        # Outputs are always the logical values: the chunk partitioning is an
+        # executor/store-internal carrier, invisible to session callers.
+        outputs = {n: (v.assemble() if isinstance(v, Chunked) else v)
+                   for n, v in ((n, sched.cache[n]) for n in dag.outputs()
+                                if n in sched.cache)}
+        return ExecutionReport(
+            states=dict(states), runtime=sched.runtime,
+            materialized=sched.materialized, skipped_mat=sched.skipped,
+            mat_seconds=sched.mat_seconds,
+            total_seconds=time.perf_counter() - t_start, outputs=outputs,
+            max_workers=sched.max_workers,
+            deduped=sched.deduped,
+            chose_compute=frozenset(dedupe_skip or ()),
+            chunk_computed=sched.chunk_computed,
+            chunk_reused=sched.chunk_reused)

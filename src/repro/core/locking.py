@@ -33,6 +33,8 @@ import threading
 import time
 from typing import Any, Callable
 
+from . import spans
+
 try:
     import fcntl
     HAVE_FLOCK = True
@@ -197,7 +199,8 @@ def update_json(path: str, fn: Callable[[Any], Any], default: Any) -> Any:
     readers never see a torn file. Returns the persisted (or current)
     value.
     """
-    with FileLock(path + ".lock"):
+    with spans.span("meta.txn", file=os.path.basename(path)) as attrs, \
+            FileLock(path + ".lock"):
         current = read_json(path, default)
         out = fn(current)
         if out is None:
@@ -205,6 +208,7 @@ def update_json(path: str, fn: Callable[[Any], Any], default: Any) -> Any:
         tmp = f"{path}.tmp-{os.getpid()}-{threading.get_ident()}"
         with open(tmp, "w") as f:
             json.dump(out, f)
+            attrs["bytes"] = f.tell()
         os.replace(tmp, path)
         return out
 

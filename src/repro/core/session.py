@@ -32,6 +32,7 @@ from .oep import plan
 from .pruning import slice_from_outputs, stale_variants
 from .remote import ObjectStore, RemoteStore, as_remote_store
 from .signature import compute_chunk_signatures, compute_signatures
+from . import spans
 from .store import Store
 from .workflow import Workflow
 
@@ -273,110 +274,115 @@ class IterativeSession:
         between nodes; the run raises
         :class:`~repro.core.executor.JobCancelled` after settling).
         ``load_shardings`` defaults to the workflow's own."""
-        if load_shardings is None:
-            load_shardings = workflow.load_shardings
-        dag = workflow.build()
-        sigs = compute_signatures(dag, nonces=nonces)
-        ev_before = (self.evictor.stats.snapshot()
-                     if self.evictor is not None else {})
+        with spans.span("session.plan"):
+            if load_shardings is None:
+                load_shardings = workflow.load_shardings
+            dag = workflow.build()
+            sigs = compute_signatures(dag, nonces=nonces)
+            ev_before = (self.evictor.stats.snapshot()
+                         if self.evictor is not None else {})
 
-        # §5.4 program slicing.
-        keep = slice_from_outputs(dag)
-        sliced = dag.subgraph(keep)
+            # §5.4 program slicing.
+            keep = slice_from_outputs(dag)
+            sliced = dag.subgraph(keep)
 
-        # Chunk-granular refinement (chunks.py): per-chunk signatures for
-        # every node they can flow to. Incrementally maintainable nodes
-        # execute per-chunk, splicing cached chunks; everything else
-        # keeps the paper's whole-value semantics.
-        chunk_plans = compute_chunk_signatures(sliced, sigs)
+            # Chunk-granular refinement (chunks.py): per-chunk signatures for
+            # every node they can flow to. Incrementally maintainable nodes
+            # execute per-chunk, splicing cached chunks; everything else
+            # keeps the paper's whole-value semantics.
+            chunk_plans = compute_chunk_signatures(sliced, sigs)
 
-        # One store stat per node per planning pass (shared NFS-style
-        # workdirs make metadata I/O expensive; the two uses below must
-        # also agree on one snapshot).
-        in_store = {n: self.store.has(sigs[n]) for n in sliced.topological()}
+            # One store stat per node per planning pass (shared NFS-style
+            # workdirs make metadata I/O expensive; the two uses below must
+            # also agree on one snapshot).
+            in_store = {n: self.store.has(sigs[n]) for n in sliced.topological()}
 
-        # §4.2 change tracking: original ⇔ signature never seen before.
-        # The store is consulted too: an equivalent materialization on disk
-        # (Def. 3) proves some session computed this signature even if the
-        # shared cost statistics have not flushed yet — without this, a
-        # session dispatched the moment a sibling's shared prefix lands
-        # (the server's prefix-first schedule does exactly that) would
-        # force-COMPUTE a value it could load.
-        original = {n for n in sliced.topological()
-                    if self.cost_model.is_original(sigs[n])
-                    and not in_store[n]}
+            # §4.2 change tracking: original ⇔ signature never seen before.
+            # The store is consulted too: an equivalent materialization on disk
+            # (Def. 3) proves some session computed this signature even if the
+            # shared cost statistics have not flushed yet — without this, a
+            # session dispatched the moment a sibling's shared prefix lands
+            # (the server's prefix-first schedule does exactly that) would
+            # force-COMPUTE a value it could load.
+            original = {n for n in sliced.topological()
+                        if self.cost_model.is_original(sigs[n])
+                        and not in_store[n]}
 
-        # §5.1 operator metrics.
-        compute_cost: dict[str, float] = {}
-        load_cost: dict[str, float | None] = {}
-        for n in sliced.topological():
-            node = sliced.nodes[n]
-            compute_cost[n] = self.cost_model.compute_cost(
-                sigs[n], hint=node.cost_hint)
-            if n in chunk_plans:
-                # Incremental pricing: the executor will recompute only
-                # the store-missing chunks, so the expected cost this
-                # iteration is the historical whole-value cost scaled by
-                # the missing fraction (omp.delta_fraction). After an
-                # append this is what makes OEP prefer compute-and-splice
-                # over loading a stale whole-value entry.
-                compute_cost[n] *= delta_fraction(chunk_plans[n],
-                                                  self.store)
-            if in_store[n]:
-                meta = self.store.meta(sigs[n])
-                # A chunked manifest's own nbytes is metadata-sized; the
-                # load cost that matters is manifest + referenced chunks.
-                nb = (meta["nbytes"]
-                      + meta.get("chunked", {}).get("chunk_bytes", 0))
-                # Per-tier l_i: a memory-resident value prices at RAM
-                # bandwidth, a remote-only one at fetch bandwidth — the
-                # cheapest tier that can actually serve the signature.
-                load_cost[n] = self.store.est_load_seconds(nb, sig=sigs[n])
-            else:
-                load_cost[n] = None
+            # §5.1 operator metrics.
+            compute_cost: dict[str, float] = {}
+            load_cost: dict[str, float | None] = {}
+            for n in sliced.topological():
+                node = sliced.nodes[n]
+                compute_cost[n] = self.cost_model.compute_cost(
+                    sigs[n], hint=node.cost_hint)
+                if n in chunk_plans:
+                    # Incremental pricing: the executor will recompute only
+                    # the store-missing chunks, so the expected cost this
+                    # iteration is the historical whole-value cost scaled by
+                    # the missing fraction (omp.delta_fraction). After an
+                    # append this is what makes OEP prefer compute-and-splice
+                    # over loading a stale whole-value entry.
+                    compute_cost[n] *= delta_fraction(chunk_plans[n],
+                                                      self.store)
+                if in_store[n]:
+                    meta = self.store.meta(sigs[n])
+                    # A chunked manifest's own nbytes is metadata-sized; the
+                    # load cost that matters is manifest + referenced chunks.
+                    nb = (meta["nbytes"]
+                          + meta.get("chunked", {}).get("chunk_bytes", 0))
+                    # Per-tier l_i: a memory-resident value prices at RAM
+                    # bandwidth, a remote-only one at fetch bandwidth — the
+                    # cheapest tier that can actually serve the signature.
+                    load_cost[n] = self.store.est_load_seconds(nb, sig=sigs[n])
+                else:
+                    load_cost[n] = None
 
-        # §5.2 OEP via max-flow. Planned LOADs are pinned with read
-        # leases so a concurrent session's eviction cannot yank them
-        # during execution; an entry that vanished in the plan→pin window
-        # (another session's purge won that race) forces a replan with
-        # its load marked unavailable — the executor's LOAD path has no
-        # compute fallback, so it must never start with a dead plan.
-        for _ in range(len(sliced) + 1):
-            states = plan(sliced, compute_cost, load_cost, original)
-            read_leases = [lease for n, s in states.items()
-                           if s is State.LOAD
-                           for lease in [self.store.acquire_read(sigs[n])]
-                           if lease is not None]
-            vanished = [n for n, s in states.items()
-                        if s is State.LOAD and not self.store.has(sigs[n])]
-            if not vanished:
-                break
-            for lease in read_leases:
-                lease.release()
-            for n in vanished:
-                load_cost[n] = None
+            # §5.2 OEP via max-flow. Planned LOADs are pinned with read
+            # leases so a concurrent session's eviction cannot yank them
+            # during execution; an entry that vanished in the plan→pin window
+            # (another session's purge won that race) forces a replan with
+            # its load marked unavailable — the executor's LOAD path has no
+            # compute fallback, so it must never start with a dead plan.
+            for _ in range(len(sliced) + 1):
+                states = plan(sliced, compute_cost, load_cost, original)
+                read_leases = [lease for n, s in states.items()
+                               if s is State.LOAD
+                               for lease in [self.store.acquire_read(sigs[n])]
+                               if lease is not None]
+                vanished = [n for n, s in states.items()
+                            if s is State.LOAD and not self.store.has(sigs[n])]
+                if not vanished:
+                    break
+                for lease in read_leases:
+                    lease.release()
+                for n in vanished:
+                    load_cost[n] = None
+            try:
+                # Purge stale materializations of original operators (§6.6:
+                # "Helix purges any previous materialization of original
+                # operators prior to execution"). Skipped in sweep mode, where
+                # sibling variants' same-name entries are not stale.
+                purged = 0
+                if self.purge_stale:
+                    # keep_chunks: a stale chunked manifest (pre-append
+                    # variant of a node this iteration re-derives) shares its
+                    # prefix chunks with the manifest about to be spliced —
+                    # the manifest goes, the still-valid chunks stay.
+                    protected = protected_chunk_sigs(chunk_plans)
+                    by_name = self.store.sigs_by_name()
+                    for old_sig in stale_variants(by_name, original, sigs):
+                        purged += self.store.delete(old_sig,
+                                                    keep_chunks=protected)
+                    # Foreign credit: the purged entries may have been paid
+                    # for by a previous session — this instance never
+                    # reserved those bytes, so the credit must not shrink
+                    # its reserved-by-me mirror (ledger-only in fleet mode).
+                    self.materializer.credit_foreign(purged)
+            except BaseException:
+                for lease in read_leases:
+                    lease.release()
+                raise
         try:
-            # Purge stale materializations of original operators (§6.6:
-            # "Helix purges any previous materialization of original
-            # operators prior to execution"). Skipped in sweep mode, where
-            # sibling variants' same-name entries are not stale.
-            purged = 0
-            if self.purge_stale:
-                # keep_chunks: a stale chunked manifest (pre-append
-                # variant of a node this iteration re-derives) shares its
-                # prefix chunks with the manifest about to be spliced —
-                # the manifest goes, the still-valid chunks stay.
-                protected = protected_chunk_sigs(chunk_plans)
-                by_name = self.store.sigs_by_name()
-                for old_sig in stale_variants(by_name, original, sigs):
-                    purged += self.store.delete(old_sig,
-                                                keep_chunks=protected)
-                # Foreign credit: the purged entries may have been paid
-                # for by a previous session — this instance never
-                # reserved those bytes, so the credit must not shrink
-                # its reserved-by-me mirror (ledger-only in fleet mode).
-                self.materializer.credit_foreign(purged)
-
             report = execute(
                 sliced, sigs, states, self.store, self.materializer,
                 load_shardings=load_shardings,
@@ -402,12 +408,13 @@ class IterativeSession:
         # dedupe turned into loads did not yield a compute measurement;
         # loads (planned or deduped) count as reuse events, which feed
         # OMP's amortization (see costs.py / omp.py multiplicity).
-        for n, secs in report.runtime.items():
-            if states[n] is State.COMPUTE and n not in report.deduped:
-                self.cost_model.record(sigs[n], compute_seconds=secs)
-            else:
-                self.cost_model.record(sigs[n], reused=True)
-        self.cost_model.save()
+        with spans.span("session.record"):
+            for n, secs in report.runtime.items():
+                if states[n] is State.COMPUTE and n not in report.deduped:
+                    self.cost_model.record(sigs[n], compute_seconds=secs)
+                else:
+                    self.cost_model.record(sigs[n], reused=True)
+            self.cost_model.save()
         self.iteration += 1
 
         evictions = {}

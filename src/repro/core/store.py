@@ -98,6 +98,7 @@ in one process or independent OS processes:
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import itertools
 import json
@@ -115,6 +116,7 @@ import numpy as np
 
 import jax
 
+from . import spans
 from .chunks import Chunked
 from .costs import TierBandwidth
 from .locking import (FileLock, SharedEwma, StorageLedger, read_json,
@@ -171,10 +173,18 @@ class PendingSave:
         self._event.wait(timeout)
 
 
-def _leaf_to_host(leaf: Any) -> Any:
-    if isinstance(leaf, jax.Array):
-        return np.asarray(jax.device_get(leaf))
-    return leaf
+def _tree_to_host(value: Any) -> Any:
+    """A host snapshot of ``value``: its ``jax.Array`` leaves copied to
+    numpy, under a ``store.to_host`` span when there is any."""
+    leaves, treedef = jax.tree_util.tree_flatten(value)
+    on_device = [i for i, leaf in enumerate(leaves)
+                 if isinstance(leaf, jax.Array)]
+    if on_device:
+        with spans.span("store.to_host",
+                        bytes=sum(leaves[i].nbytes for i in on_device)):
+            for i in on_device:
+                leaves[i] = np.asarray(jax.device_get(leaves[i]))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def tree_nbytes(value: Any) -> int:
@@ -566,12 +576,22 @@ class Store:
     def save(self, sig: str, name: str, value: Any,
              extra_meta: dict | None = None, *,
              _tier_admit: bool = True) -> SaveInfo:
-        if isinstance(value, Chunked):
-            return self._save_chunked(sig, name, value, extra_meta)
+        with spans.span("store.save") as attrs:
+            if isinstance(value, Chunked):
+                info = self._save_chunked(sig, name, value, extra_meta)
+                attrs.update(tier="local", bytes=info.nbytes)
+                return info
+            return self._save(sig, name, value, extra_meta, _tier_admit,
+                              attrs)
+
+    def _save(self, sig: str, name: str, value: Any,
+              extra_meta: dict | None, tier_admit: bool,
+              attrs: dict) -> SaveInfo:
+        """``save`` of a whole value; ``attrs`` are its span's."""
         t0 = time.perf_counter()
-        host_value = jax.tree_util.tree_map(_leaf_to_host, value)
+        host_value = _tree_to_host(value)
         extra = extra_meta or {}
-        if (self._mem is not None and self._mem.writeback and _tier_admit
+        if (self._mem is not None and self._mem.writeback and tier_admit
                 and not extra.get("is_chunk") and "chunked" not in extra):
             # Write-back mode: the save lands in the memory tier only;
             # the disk write happens at demotion (_spill_from_mem) or an
@@ -586,6 +606,7 @@ class Store:
             wb_meta.update(extra)
             if self._mem.put(sig, host_value, wb_nbytes, name=name,
                              meta=wb_meta, state="dirty"):
+                attrs.update(tier="memory", bytes=wb_nbytes)
                 return SaveInfo(nbytes=0,
                                 seconds=time.perf_counter() - t0)
             # Value exceeds the whole memory budget — write through.
@@ -647,7 +668,7 @@ class Store:
         except BaseException:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
-        if self._mem is not None and _tier_admit and not meta.get("chunked"):
+        if self._mem is not None and tier_admit and not meta.get("chunked"):
             # Write-through admission into the memory tier: the host
             # snapshot is already in hand, so promoting it is free and
             # makes the next same-process load a pointer handoff.
@@ -659,6 +680,7 @@ class Store:
         # Shared-signature saves additionally upload_now() before their
         # compute lease releases (executor).
         self._enqueue_upload(sig, meta)
+        attrs.update(tier="local", bytes=nbytes)
         return SaveInfo(nbytes=nbytes, seconds=seconds, replaced=replaced,
                         replaced_nbytes=replaced_nbytes)
 
@@ -774,8 +796,15 @@ class Store:
         ``max_inflight_bytes`` so queued materializations cannot exhaust
         host memory.
         """
-        host_value = jax.tree_util.tree_map(_leaf_to_host, value)
+        with spans.span("store.save", tier="queued") as attrs:
+            return self._save_enqueue(sig, name, value, extra_meta, attrs)
+
+    def _save_enqueue(self, sig: str, name: str, value: Any,
+                      extra_meta: dict | None, attrs: dict) -> PendingSave:
+        """``save_enqueue``; ``attrs`` are its span's."""
+        host_value = _tree_to_host(value)
         est = tree_nbytes(host_value)
+        attrs["bytes"] = est
         pending = PendingSave()
         if (self._mem is not None and not isinstance(value, Chunked)
                 and not (extra_meta or {}).get("is_chunk")):
@@ -793,7 +822,8 @@ class Store:
                 self._writer_cv.wait()
             self._inflight_bytes += est
             self._writer_queue.append(
-                ("save", sig, name, host_value, extra_meta, est, pending))
+                ("save", sig, name, host_value, extra_meta, est, pending,
+                 contextvars.copy_context()))
             if self._writer_thread is None or not self._writer_thread.is_alive():
                 self._writer_thread = threading.Thread(
                     target=self._writer_loop, name="store-writer", daemon=True)
@@ -812,21 +842,23 @@ class Store:
                 item = self._writer_queue.popleft()
                 if item[0] == "save":
                     self._writer_active.add(item[1])
+            # Each item runs under the context of the thread that queued
+            # it, so its spans keep that job and parent.
             if item[0] == "offload":
                 # Async device→host snapshot of a memory-tier entry
                 # (zero-copy sharded loads admit jax.Arrays; this moves
                 # them off-device off the critical path).
                 try:
-                    self._mem_offload_run(item[1])
+                    item[2].run(self._mem_offload_run, item[1])
                 except Exception:
                     pass   # advisory: the device copy keeps serving
                 with self._writer_cv:
                     self._writer_cv.notify_all()
                 continue
-            _, sig, name, host_value, extra_meta, est, pending = item
+            _, sig, name, host_value, extra_meta, est, pending, ctx = item
             try:
-                info = self.save(sig, name, host_value,
-                                 extra_meta=extra_meta)
+                info = ctx.run(self.save, sig, name, host_value,
+                               extra_meta=extra_meta)
                 pending._finish(info)
             except BaseException as e:
                 pending._finish(None, e)
@@ -858,7 +890,8 @@ class Store:
         the same ``writer_drain`` barrier) that owns every other
         off-critical-path materialization write."""
         with self._writer_cv:
-            self._writer_queue.append(("offload", sig))
+            self._writer_queue.append(("offload", sig,
+                                       contextvars.copy_context()))
             if self._writer_thread is None \
                     or not self._writer_thread.is_alive():
                 self._writer_thread = threading.Thread(
@@ -878,7 +911,7 @@ class Store:
         if ent is None or not ent.has_device:
             return
         device_value = ent.value
-        host_value = jax.tree_util.tree_map(_leaf_to_host, device_value)
+        host_value = _tree_to_host(device_value)
         self._mem.replace_value(sig, host_value, expect=device_value)
 
     def _spill_from_mem(self, sig: str, ent: MemEntry) -> None:
@@ -1066,6 +1099,12 @@ class Store:
         bump stays tier-local), and every successful disk/remote load
         read-through promotes its value for the next caller.
         """
+        with spans.span("store.load") as attrs:
+            return self._load(sig, sharding_for_leaf, attrs)
+
+    def _load(self, sig: str, sharding_for_leaf, attrs: dict
+              ) -> tuple[Any, float]:
+        """``load``; ``attrs`` are its span's."""
         if self._mem is not None:
             t0 = time.perf_counter()
             ent = self._mem.get(sig)
@@ -1074,6 +1113,7 @@ class Store:
                 if sharding_for_leaf is not None:
                     value = self._place_leaves(value, sharding_for_leaf)
                 seconds = time.perf_counter() - t0
+                attrs.update(tier="memory", bytes=ent.nbytes)
                 self._tier_bw.observe("memory", "read", ent.nbytes,
                                       seconds)
                 with self._stats_lock:
@@ -1102,6 +1142,8 @@ class Store:
                         int(meta.get("nbytes", 0) or tree_nbytes(value)),
                         name=meta.get("name", ""), meta=meta,
                         state="durable")
+                attrs.update(tier="remote" if fetch_secs else "local",
+                             bytes=int(meta.get("nbytes", 0) or 0))
                 return value, seconds + fetch_secs
             except FileNotFoundError:
                 # Either we raced an overwrite of the same signature (tmp
@@ -1128,13 +1170,17 @@ class Store:
         leaves the callback declines (None) pass through untouched."""
         leaves, treedef = jax.tree_util.tree_flatten(value)
         placed = []
-        for i, leaf in enumerate(leaves):
-            if isinstance(leaf, (np.ndarray, jax.Array)):
-                sharding = sharding_for_leaf(
-                    i, tuple(leaf.shape), np.dtype(leaf.dtype))
-                if sharding is not None:
-                    leaf = jax.device_put(leaf, sharding)
-            placed.append(leaf)
+        nbytes = 0
+        with spans.span("store.to_device") as attrs:
+            for i, leaf in enumerate(leaves):
+                if isinstance(leaf, (np.ndarray, jax.Array)):
+                    sharding = sharding_for_leaf(
+                        i, tuple(leaf.shape), np.dtype(leaf.dtype))
+                    if sharding is not None:
+                        leaf = jax.device_put(leaf, sharding)
+                        nbytes += leaf.nbytes
+                placed.append(leaf)
+            attrs["bytes"] = nbytes
         return jax.tree_util.tree_unflatten(treedef, placed)
 
     def _load_once(self, sig: str, sharding_for_leaf
@@ -1174,10 +1220,12 @@ class Store:
                 sharding = (sharding_for_leaf(i, shape, dtype)
                             if sharding_for_leaf else None)
                 if sharding is not None:
-                    mm = np.load(path, mmap_mode="r").view(dtype)
-                    return jax.make_array_from_callback(
-                        shape, sharding,
-                        lambda idx, _mm=mm: np.ascontiguousarray(_mm[idx]))
+                    with spans.span("store.to_device",
+                                    bytes=int(np.prod(shape)) * dtype.itemsize):
+                        mm = np.load(path, mmap_mode="r").view(dtype)
+                        return jax.make_array_from_callback(
+                            shape, sharding,
+                            lambda idx, _mm=mm: np.ascontiguousarray(_mm[idx]))
                 return np.load(path).view(dtype)
             with open(path, "rb") as f:
                 return pickle.load(f)

@@ -158,8 +158,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     if probe:
         # Cost-accurate probe: XLA's cost_analysis (and the HLO text) count
         # while-loop bodies ONCE, so scanned models under-report. The probe
-        # unrolls the layer stack and runs ONE microbatch; roofline scales
-        # the per-microbatch terms back up by the real grad_accum.
+        # unrolls the layer stack and runs ONE microbatch; a reader scales
+        # the per-microbatch terms back up by accum_scale (the real
+        # grad_accum).
         overrides["unroll"] = True
         accum_scale = overrides.get("grad_accum", cfg.grad_accum)
         overrides["grad_accum"] = 1
@@ -176,7 +177,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     sh0 = shapes_lib.SHAPES[shape_name]
     patched = sh0
     if probe and sh0.kind == "train" and accum_scale > 1:
-        # probe one real microbatch; roofline scales terms ×accum_scale
+        # probe one real microbatch; a reader scales terms ×accum_scale
         patched = dataclasses.replace(sh0, batch=sh0.batch // accum_scale)
     t0 = time.perf_counter()
     try:
@@ -195,7 +196,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             rec["compile_s"] = time.perf_counter() - t1
             if os.environ.get("DRYRUN_VERBOSE"):
                 print(compiled.memory_analysis())   # proves it fits
-                print(compiled.cost_analysis())     # FLOPs/bytes for roofline
+                print(compiled.cost_analysis())     # FLOPs/bytes
             try:
                 ma = compiled.memory_analysis()
                 rec["memory_analysis"] = {
@@ -258,7 +259,8 @@ def main() -> None:
     ap.add_argument("--window-cache", action="store_true")
     ap.add_argument("--probe", action="store_true",
                     help="unrolled, single-microbatch cost probe "
-                         "(accurate cost_analysis; see roofline.py)")
+                         "(cost_analysis counts a scanned loop body once; "
+                         "scale its terms by the record's accum_scale)")
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--skip-existing", action="store_true")

@@ -207,7 +207,8 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
     returns the updated cache — S == 1 is the decode step, S > 1 prefill.
     """
     b, s = tokens.shape
-    h = embed_lookup(cfg, params["embed"], tokens)
+    with jax.named_scope("embed"):
+        h = embed_lookup(cfg, params["embed"], tokens)
     if vision_embeds is not None:
         npatch = vision_embeds.shape[1]
         h = jnp.concatenate(
@@ -231,10 +232,12 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
                                         mrope_positions)
     aux = aux + aux0
 
-    h = layers.rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = layers.rmsnorm(h, params["final_norm"], cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", h, head.astype(h.dtype))
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,dv->bsv", h, head.astype(h.dtype))
     logits = constrain(logits, batch_axes(), None,
                        None if "model" in batch_axes() else "model")
     if new_cache is not None and cache is not None:
@@ -256,22 +259,27 @@ def _attn_stack(cfg, params, h, positions, cache, mrope_positions):
         else:
             p, window = xs
             kc = vc = None
-        x = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
-        attn_out, new_kv = layers.attn_block(
-            cfg, p["attn"], x, positions, window=window,
-            kv_cache=(kc, vc) if has_cache else None,
-            cache_pos=base if has_cache else None,
-            mrope_positions=mrope_positions)
+        with jax.named_scope("norm"):
+            x = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
+        with jax.named_scope("attention"):
+            attn_out, new_kv = layers.attn_block(
+                cfg, p["attn"], x, positions, window=window,
+                kv_cache=(kc, vc) if has_cache else None,
+                cache_pos=base if has_cache else None,
+                mrope_positions=mrope_positions)
         h = h + attn_out
-        x = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            x = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
         if is_moe:
             moe_fn = {"shard_map": moe_lib.moe_block_sharded,
                          "a2a": moe_lib.moe_block_a2a}.get(
                              cfg.moe_impl, moe_lib.moe_block)
-            ffn_out, a = moe_fn(cfg.moe, p["moe"], x)
+            with jax.named_scope("mlp"):
+                ffn_out, a = moe_fn(cfg.moe, p["moe"], x)
             aux = aux + a
         else:
-            ffn_out = layers.mlp_block(p["mlp"], x)
+            with jax.named_scope("mlp"):
+                ffn_out = layers.mlp_block(p["mlp"], x)
         h = h + ffn_out
         h = constrain(h, batch_axes(), None, None)
         if has_cache:

@@ -22,6 +22,7 @@ every session regardless of who currently holds the slots.
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 from typing import Callable
 
@@ -59,16 +60,17 @@ class SharedWorkerPool:
         Returns the number of workers that actually ran (≥ 1). Exceptions
         from the inline worker propagate; borrowed workers run the same
         executor loop, which routes its failures through the executor's
-        own error channel.
+        own error channel. Each borrowed worker runs under a copy of the
+        caller's context, so its spans keep the caller's job and parent.
         """
         threads: list[threading.Thread] = []
         for _ in range(max(0, int(want) - 1)):
             if not self._try_borrow():
                 break
 
-            def slot() -> None:
+            def slot(ctx=contextvars.copy_context()) -> None:
                 try:
-                    fn()
+                    ctx.run(fn)
                 finally:
                     self._return_slot()
 

@@ -54,6 +54,7 @@ from ..core.omp import Policy, delta_fraction
 from ..core.pruning import slice_from_outputs
 from ..core.remote import ObjectStore, RemoteStore, as_remote_store
 from ..core.session import IterationReport, IterativeSession
+from ..core import spans
 from ..core.signature import compute_chunk_signatures, compute_signatures
 from ..core.store import Store
 from ..core.workflow import Workflow
@@ -144,6 +145,13 @@ class Job:
     # fair-share accounting, quota ledgers, and the tenant-scoped
     # storage budget; "default" when tenancy is not configured.
     tenant: str = "default"
+
+    def mark_dispatched(self) -> None:
+        """Stamp the end of the job's wait for a session slot (dispatch,
+        or cancellation while queued) and record it as ``server.queue``."""
+        self.dispatched_at = time.perf_counter()
+        spans.record("server.queue", int(self.submitted_at * 1e9),
+                     int(self.dispatched_at * 1e9), job=self.id)
 
     @property
     def queued_seconds(self) -> float:
@@ -719,7 +727,7 @@ class SessionServer:
             else:
                 j.status = "cancelled"
                 j.error = JobCancelled(reason)
-                j.dispatched_at = time.perf_counter()
+                j.mark_dispatched()
                 j.finished_at = j.dispatched_at
                 self.scheduler.remove(j)
                 self._retain_finished_locked(j)
@@ -894,7 +902,7 @@ class SessionServer:
                     return
                 self._queue.remove(job)
                 job.status = "running"
-                job.dispatched_at = time.perf_counter()
+                job.mark_dispatched()
                 self._running[job.id] = job
                 self.dispatch_log.append(job.name)
                 if isinstance(self.scheduler, TenantScheduler):
@@ -931,6 +939,14 @@ class SessionServer:
                             quota_bytes=spec.storage_bytes)
 
     def _run_job(self, job: Job) -> None:
+        # The job's root span; it ends before the job is marked done, so
+        # that a client that wakes on done finds the whole tree recorded.
+        with spans.span("server.job", job=job.id) as attrs:
+            self._run_job_body(job)
+            attrs["status"] = job.status
+        job.done.set()
+
+    def _run_job_body(self, job: Job) -> None:
         t0 = time.perf_counter()
         timer: threading.Timer | None = None
         if job.timeout is not None:
@@ -940,33 +956,34 @@ class SessionServer:
             timer.daemon = True
             timer.start()
         try:
-            sess = IterativeSession(
-                self.workdir,
-                engine=dataclasses.replace(
-                    self.engine_config, horizon=self.horizon,
-                    share_nondet=self.share_nondet,
-                    dedupe_inflight=self.dedupe_inflight),
-                # The session reuses this server's store instance; its
-                # own remote-construction path must stay cold.
-                storage=dataclasses.replace(
-                    self.store_config, shared_budget=True,
-                    purge_stale=self.purge_stale, remote=None,
-                    evict_to_admit=self.evict_to_admit),
-                resilience=self.resilience_config,
-                store=self.store, cost_model=self.cost_model,
-                worker_pool=self.pool,
-                # One shared fleet evictor (live-multiplicity veto from
-                # the scheduler); None keeps refuse-on-exhausted.
-                evictor=self.evictor,
-                # Tenant-scoped budget ledger (None without tenancy).
-                ledger=self._job_ledger(job),
-                # Observed amortization belongs to the globally-aware
-                # schedules; "fifo" keeps OMP purely static so it
-                # remains a faithful PR 2 baseline (pass horizon=K to
-                # match).
-                multiplicity=(self._omp_multiplicity
-                              if self.scheduler.mode in ("prefix", "fair")
-                              else None))
+            with spans.span("session.init"):
+                sess = IterativeSession(
+                    self.workdir,
+                    engine=dataclasses.replace(
+                        self.engine_config, horizon=self.horizon,
+                        share_nondet=self.share_nondet,
+                        dedupe_inflight=self.dedupe_inflight),
+                    # The session reuses this server's store instance; its
+                    # own remote-construction path must stay cold.
+                    storage=dataclasses.replace(
+                        self.store_config, shared_budget=True,
+                        purge_stale=self.purge_stale, remote=None,
+                        evict_to_admit=self.evict_to_admit),
+                    resilience=self.resilience_config,
+                    store=self.store, cost_model=self.cost_model,
+                    worker_pool=self.pool,
+                    # One shared fleet evictor (live-multiplicity veto from
+                    # the scheduler); None keeps refuse-on-exhausted.
+                    evictor=self.evictor,
+                    # Tenant-scoped budget ledger (None without tenancy).
+                    ledger=self._job_ledger(job),
+                    # Observed amortization belongs to the globally-aware
+                    # schedules; "fifo" keeps OMP purely static so it
+                    # remains a faithful baseline of the static-horizon
+                    # server (pass horizon=K to match).
+                    multiplicity=(self._omp_multiplicity
+                                  if self.scheduler.mode in ("prefix", "fair")
+                                  else None))
             job.report = sess.run(job.workflow, nonces=self.nonces,
                                   share_sigs=self._share_view,
                                   cancel=job.cancel_event)
@@ -997,7 +1014,6 @@ class SessionServer:
                 self.scheduler.remove(job)
                 self._retain_finished_locked(job)
                 self._cv.notify_all()
-            job.done.set()
 
     def _retain_finished_locked(self, job: Job) -> None:
         """Bound the finished-job history: a long-running server must not
@@ -1065,7 +1081,7 @@ class SessionServer:
                 job.error = JobCancelled("server shut down")
                 # Freeze queued_seconds at cancellation time (it is
                 # computed against "now" while dispatched_at is unset).
-                job.dispatched_at = time.perf_counter()
+                job.mark_dispatched()
                 job.finished_at = job.dispatched_at
                 self.scheduler.remove(job)
                 job.done.set()

@@ -73,7 +73,8 @@ def loss_fn(cfg: ArchConfig, params: Any, batch: dict) -> tuple[jax.Array, dict]
             mrope_positions=batch.get("mrope_positions"))
     logits = out.logits[:, :-1]
     targets = tokens[:, 1:]
-    loss = _xent(logits, targets, mask[:, 1:], impl=cfg.xent_impl)
+    with jax.named_scope("loss"):
+        loss = _xent(logits, targets, mask[:, 1:], impl=cfg.xent_impl)
     aux = 0.01 * out.aux_loss
     return loss + aux, {"loss": loss, "aux_loss": out.aux_loss}
 
@@ -124,12 +125,14 @@ def train_step(cfg: ArchConfig, state: TrainState, batch: dict, *,
             body, (zero_grads, zero_metrics), micro, unroll=cfg.unroll)
         grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
 
-    grads, gnorm = adamw.clip_by_global_norm(grads, clip_norm)
-    # schedule is 1-indexed: step 0 would otherwise get lr == 0
-    lr = schedules.warmup_cosine(
-        state.opt.step + 1, peak_lr=peak_lr, warmup_steps=warmup_steps,
-        total_steps=total_steps)
-    new_params, new_opt = adamw.update(state.params, grads, state.opt, lr=lr)
+    with jax.named_scope("optimizer"):
+        grads, gnorm = adamw.clip_by_global_norm(grads, clip_norm)
+        # schedule is 1-indexed: step 0 would otherwise get lr == 0
+        lr = schedules.warmup_cosine(
+            state.opt.step + 1, peak_lr=peak_lr, warmup_steps=warmup_steps,
+            total_steps=total_steps)
+        new_params, new_opt = adamw.update(state.params, grads, state.opt,
+                                           lr=lr)
     metrics = dict(metrics, grad_norm=gnorm, lr=lr,
                    step=new_opt.step.astype(jnp.float32))
     return TrainState(params=new_params, opt=new_opt), metrics

@@ -6,6 +6,7 @@ import threading
 import numpy as np
 import pytest
 
+from repro.core import spans
 from repro.core.dag import DAG, Node, State
 from repro.core.executor import execute
 from repro.core.omp import Materializer, Policy
@@ -151,12 +152,28 @@ def test_prefetch_depth_bounds_resident_loads(tmp_path):
                   max_workers=4, prefetch_depth=2)
     assert rep.outputs[f"C{k-1}"] == pytest.approx(
         sum(range(k)) * np.ones(N))
-    assert rep.peak_resident_loads <= 3
+    assert _peak_resident_loads(k) <= 3
     # and with a generous depth everything may be prefetched
-    rep2 = execute(dag, sigs, states, store,
-                   Materializer(policy=Policy.NEVER),
-                   max_workers=4, prefetch_depth=k)
-    assert rep2.peak_resident_loads <= k
+    execute(dag, sigs, states, store, Materializer(policy=Policy.NEVER),
+            max_workers=4, prefetch_depth=k)
+    assert _peak_resident_loads(k) <= k
+
+
+def _peak_resident_loads(k: int) -> int:
+    """The most loads resident at once in the last ``execute``, by its
+    spans: load ``L{i}`` is resident at least from its node's start until
+    its one consumer ``C{i}`` ends."""
+    recorded = spans.recorded()
+    run = [s for s in recorded if s.name == "executor.run"][-1]
+    node = {s.attrs["node"]: s for s in recorded
+            if s.name == "executor.node" and s.parent == run.id}
+    edges = sorted([(node[f"L{i}"].start_ns, 1) for i in range(k)]
+                   + [(node[f"C{i}"].end_ns, -1) for i in range(k)])
+    peak = held = 0
+    for _, step in edges:
+        held += step
+        peak = max(peak, held)
+    return peak
 
 
 def test_worker_exception_propagates(tmp_path):
