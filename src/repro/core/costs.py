@@ -181,13 +181,24 @@ class TierBandwidth:
     knobs: ~8 GB/s for a host-RAM pointer handoff (the measured EWMA
     takes over after the first hit), 500 MB/s local disk (the historical
     default), 100 MB/s + 1 ms for an object store round-trip.
+
+    The device tier (devtier.py) moves no bytes on a hit: its price is
+    the handoff's latency alone, an EWMA of measured hit seconds under
+    ``dev_handoff_s`` with a 10 µs prior. ``transfer`` prices a copy
+    between the device and the host (a released device entry going down
+    to host RAM, and coming back), measured on such copies, with a
+    ~1.5 GB/s prior: a v5e host copied the chip benchmark's 5 GB
+    TrainState at 0.65–1.9 GB/s.
     """
 
-    _KEYS = {"memory": ("mem_read", "mem_write"),
+    _KEYS = {"transfer": ("xfer_read", "xfer_write"),
+             "memory": ("mem_read", "mem_write"),
              "local": ("read", "write"),
              "remote": ("remote_read", "remote_write")}
-    _FLOOR_BW = {"memory": 8e9, "local": 500e6, "remote": 100e6}
-    _LATENCY = {"memory": 1e-6, "local": 1e-4, "remote": 1e-3}
+    _FLOOR_BW = {"transfer": 1.5e9, "memory": 8e9, "local": 500e6,
+                 "remote": 100e6}
+    _LATENCY = {"transfer": 1e-4, "memory": 1e-6, "local": 1e-4,
+                "remote": 1e-3, "device": 1e-5}
 
     def __init__(self, ewma):
         self._ewma = ewma
@@ -196,6 +207,9 @@ class TierBandwidth:
                 seconds: float) -> None:
         """Record one measured transfer (``kind`` is "read"/"write")."""
         if nbytes <= 0 or seconds <= 0:
+            return
+        if tier == "device":
+            self._ewma.update("dev_handoff_s", float(seconds))
             return
         rk, wk = self._KEYS[tier]
         self._ewma.update(rk if kind == "read" else wk,
@@ -210,4 +224,7 @@ class TierBandwidth:
 
     def est_load_seconds(self, tier: str, nbytes: float) -> float:
         """Estimated seconds to serve ``nbytes`` from ``tier``."""
+        if tier == "device":
+            return (self._ewma.get("dev_handoff_s")
+                    or self._LATENCY["device"])
         return float(nbytes) / self.bandwidth(tier) + self._LATENCY[tier]

@@ -294,16 +294,35 @@ class _Scheduler:
         plan = self.chunk_plans.get(name)
         with self.cv:
             raw = [self.cache[p] for p in node.parents]
-        if plan is not None:
+            held = {self.sigs[n] for n in self.cache}
+        try:
+            return self._compute(name, node, plan, raw, held)
+        except Exception as e:
+            if not self.store.device_out_of_memory(e, held):
+                raise
+        # Out of device memory with the device tier's entries now gone;
+        # run once more, outside the handler, so that what the failed
+        # attempt held (its frames, via the traceback) is freed first.
+        return self._compute(name, node, plan, raw, held)
+
+    def _compute(self, name: str, node, plan, raw: list,
+                 held: set) -> tuple[Any, float]:
+        """One run of a COMPUTE node's function (or chunk plan) on its
+        parents' values ``raw``."""
+        # The store's device tier makes room before the compute and
+        # records its working set after (outside the timed part).
+        with self.store.device_compute(name, held):
+            if plan is not None:
+                t0 = time.perf_counter()
+                value = self._run_chunked(name, node, plan, raw)
+                return value, time.perf_counter() - t0
+            # Opaque consumers always see the assembled (logical) value: a
+            # chunked parent's partitioning is an executor-internal carrier.
+            args = [v.assemble() if isinstance(v, Chunked) else v
+                    for v in raw]
             t0 = time.perf_counter()
-            value = self._run_chunked(name, node, plan, raw)
+            value = _block(node.fn(*args))
             return value, time.perf_counter() - t0
-        # Opaque consumers always see the assembled (logical) value: a
-        # chunked parent's partitioning is an executor-internal carrier.
-        args = [v.assemble() if isinstance(v, Chunked) else v for v in raw]
-        t0 = time.perf_counter()
-        value = _block(node.fn(*args))
-        return value, time.perf_counter() - t0
 
     # -- chunk-granular execution (incremental recomputation) --------------
     def _chunk_from_store(self, csig: str):
@@ -467,10 +486,11 @@ class _Scheduler:
             return
         n_waiting = lease.waiters()
         est_bytes = tree_nbytes(value)
-        # Write decisions price the durable (disk) tier: the value is not
-        # resident on any tier yet, and the waiters this persist serves
-        # will read it from disk/remote, not this process's memory tier.
-        est_load = self.store.est_load_seconds(est_bytes)
+        # Priced at the tier this save keeps the value in: a handoff for
+        # device leaves in a write-back store's device tier (the waiters
+        # of this process find it there), else the disk tier, which the
+        # waiters of other processes read.
+        est_load = self.store.est_reload_seconds(value, est_bytes)
         if (sig not in self.share_sigs and n_waiting == 0
                 and est_load >= compute_seconds):
             return  # nobody wants it and recompute is cheaper than load
@@ -606,12 +626,13 @@ class _Scheduler:
             verdict = "stored"
         else:
             est_bytes = tree_nbytes(value)
-            # Durable-tier price on purpose (no sig): Algorithm 2 is
-            # deciding whether a *future* load beats a recompute, and
-            # the future loader pays the disk tier — the memory tier's
-            # zero-copy hit is a same-process bonus on top, not the
-            # cost this write must amortize.
-            est_load = self.store.est_load_seconds(est_bytes)
+            # Algorithm 2 weighs a *future* load against a recompute, so
+            # the load is priced at the tier this save keeps the value
+            # in: a write-back store's device tier holds device leaves
+            # where they are (a handoff); every other value is priced at
+            # the disk tier, where a write-through save lands and where
+            # a write-back host entry goes once memory pressure spills it.
+            est_load = self.store.est_reload_seconds(value, est_bytes)
             # evict_inline=False: this runs under the scheduler lock, and
             # eviction is store I/O (index scan + deletes) that every
             # worker would otherwise stall behind — an over-budget

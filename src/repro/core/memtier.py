@@ -26,9 +26,13 @@ Semantics:
   the ``memtier:before_spill`` / ``memtier:after_spill`` crash points —
   and only then dropped.
 * **Async device offload** — values admitted with ``jax.Array`` leaves
-  (sharded loads) are handed to the store's writer-queue machinery to be
-  snapshotted to host RAM off the critical path; until the offload runs
-  the device arrays are served as-is (zero-copy either way).
+  (read-through promotions of sharded disk loads) are handed to the
+  store's writer-queue machinery to be snapshotted to host RAM off the
+  critical path; until the offload runs the device arrays are served
+  as-is (zero-copy either way). A write-back *save* of device leaves
+  never comes here: the store's device tier (devtier.py) holds it on the
+  device, and this tier sees it only as a host snapshot if the device
+  tier demotes it.
 
 Entry states:
 

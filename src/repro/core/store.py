@@ -95,9 +95,19 @@ in one process or independent OS processes:
   prices the cheapest tier that can serve a signature via per-tier EWMA
   bandwidths (``costs.TierBandwidth`` over the same ``.fleet/bw.json``)
   and ``tier_status`` reports one unified per-tier record.
+* **Device tier** (devtier.py): in write-back mode a save whose value
+  has ``jax.Array`` leaves keeps them where they are — no host snapshot
+  — and a hit hands the same pytree back, so device values live in the
+  device tier, not the memory tier. Loads of such a signature are
+  priced as a handoff. Before a node computes, unpinned entries are
+  released until the device has room for the largest working set
+  recorded; a released entry goes down to the memory tier only where
+  Algorithm 2 holds at that tier's price, else it is dropped. Where the
+  devices report no memory statistics (the CPU) the tier is off.
 """
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import dataclasses
 import itertools
@@ -119,6 +129,7 @@ import jax
 from . import spans
 from .chunks import Chunked
 from .costs import TierBandwidth
+from .devtier import DevEntry, DevTier
 from .locking import (FileLock, SharedEwma, StorageLedger, read_json,
                       update_json)
 from .memtier import MemEntry, MemTier
@@ -268,16 +279,22 @@ class ComputeLease:
 class ReadPin:
     """A held planned-LOAD pin spanning tiers.
 
-    Wraps the local shared ``flock`` (blocks this host's eviction) and,
-    when the entry only exists remotely, a remote TTL pin (blocks every
-    host's remote eviction until the load lands)."""
+    Wraps the local shared ``flock`` (blocks this host's eviction),
+    when the entry only exists remotely a remote TTL pin (blocks every
+    host's remote eviction until the load lands), and when it is
+    resident in the device tier a pin there (blocks its release)."""
 
-    def __init__(self, lock: FileLock, remote_pin=None):
+    def __init__(self, lock: FileLock, remote_pin=None,
+                 unpin_device: Callable[[], None] | None = None):
         self._lock: FileLock | None = lock
         self._remote_pin = remote_pin
+        self._unpin_device = unpin_device
 
     def release(self) -> None:
-        """Drop both pins (idempotent)."""
+        """Drop every pin (idempotent)."""
+        if self._unpin_device is not None:
+            self._unpin_device()
+            self._unpin_device = None
         if self._remote_pin is not None:
             self._remote_pin.release()
             self._remote_pin = None
@@ -375,6 +392,15 @@ class Store:
                 offload=self._mem_offload_enqueue,
                 est_disk_load=lambda nb:
                     self._tier_bw.est_load_seconds("local", nb))
+        # Device tier (devtier.py) above it, in write-back mode only: a
+        # write-through save must reach the disk, and a device value
+        # would then have to come to the host anyway.
+        self._dev: DevTier | None = None
+        if self._mem is not None and mem_writeback:
+            self._dev = DevTier(
+                est_host_load=lambda nb:
+                    self._tier_bw.est_load_seconds("transfer", nb),
+                demote=self._demote_from_device)
         if heal:
             self._reap_stale_tmp()
             self._reap_fleet_metadata()
@@ -519,13 +545,15 @@ class Store:
         return self._mem is not None and self._mem.has(sig)
 
     def has(self, sig: str) -> bool:
-        """Entry reachable on any tier: local disk, memory-resident
-        (possibly memory-only in write-back mode — still loadable
-        in-process), or committed in the remote tier (loadable through
-        the read-through fetch path). This is the planner's reuse test.
+        """Entry reachable on any tier: local disk, device- or
+        memory-resident (possibly memory-only in write-back mode — still
+        loadable in-process), or committed in the remote tier (loadable
+        through the read-through fetch path). This is the planner's reuse test.
         Remote presence may be cached a couple of seconds;
         dedupe-critical paths use :meth:`has_fresh`."""
         if self.has_local(sig):
+            return True
+        if self._dev is not None and self._dev.has(sig):
             return True
         if self._mem is not None and self._mem.has(sig):
             return True
@@ -541,6 +569,8 @@ class Store:
         compute-once. (Also refreshes the cache, so the caller's
         follow-up ``has``/``load`` sees the entry.)"""
         if self.has_local(sig):
+            return True
+        if self._dev is not None and self._dev.has(sig):
             return True
         if self._mem is not None and self._mem.has(sig):
             return True
@@ -588,9 +618,13 @@ class Store:
               extra_meta: dict | None, tier_admit: bool,
               attrs: dict) -> SaveInfo:
         """``save`` of a whole value; ``attrs`` are its span's."""
+        if tier_admit:
+            info = self._device_save(sig, name, value, extra_meta, attrs)
+            if info is not None:
+                return info
         t0 = time.perf_counter()
-        host_value = _tree_to_host(value)
         extra = extra_meta or {}
+        host_value = _tree_to_host(value)
         if (self._mem is not None and self._mem.writeback and tier_admit
                 and not extra.get("is_chunk") and "chunked" not in extra):
             # Write-back mode: the save lands in the memory tier only;
@@ -802,10 +836,14 @@ class Store:
     def _save_enqueue(self, sig: str, name: str, value: Any,
                       extra_meta: dict | None, attrs: dict) -> PendingSave:
         """``save_enqueue``; ``attrs`` are its span's."""
+        pending = PendingSave()
+        info = self._device_save(sig, name, value, extra_meta, attrs)
+        if info is not None:
+            pending._finish(info)
+            return pending
         host_value = _tree_to_host(value)
         est = tree_nbytes(host_value)
         attrs["bytes"] = est
-        pending = PendingSave()
         if (self._mem is not None and not isinstance(value, Chunked)
                 and not (extra_meta or {}).get("is_chunk")):
             # Admit before the disk write lands ("queued": the writer
@@ -949,9 +987,75 @@ class Store:
         self._crash_point("memtier:after_spill")
 
     def mem_flush(self) -> int:
-        """Write-back barrier: spill every dirty memory-tier entry to
-        disk (no-op without the tier). Returns the number spilled."""
-        return self._mem.flush() if self._mem is not None else 0
+        """Write-back barrier: spill every dirty memory-tier entry and
+        every device-tier entry to disk (no-op without the tiers); the
+        device entries stay resident. Returns the number spilled."""
+        n = self._mem.flush() if self._mem is not None else 0
+        for sig, ent in self._dev.items() if self._dev is not None else ():
+            self._spill_from_mem(sig, ent)
+            n += 1
+        return n
+
+    # -- device tier (devtier.py) ----------------------------------------
+    def _device_save(self, sig: str, name: str, value: Any,
+                     extra_meta: dict | None, attrs: dict
+                     ) -> SaveInfo | None:
+        """Write-back mode, device leaves: keep the value on the device
+        as it is, with no copy to the host. None, doing nothing, where
+        the device tier does not take it. Chunk entries and manifests
+        always take the host path (see ``_save``)."""
+        extra = extra_meta or {}
+        if (self._dev is None or isinstance(value, Chunked)
+                or extra.get("is_chunk") or "chunked" in extra
+                or not self._dev.would_admit(value)):
+            return None
+        t0 = time.perf_counter()
+        nbytes = tree_nbytes(value)
+        self._dev.admit(sig, value, nbytes, name=name,
+                        meta={"name": name, "sig": sig, "nbytes": nbytes,
+                              "created": time.time(), **extra})
+        attrs.update(tier="device", bytes=nbytes)
+        return SaveInfo(nbytes=0, seconds=time.perf_counter() - t0)
+
+    def _demote_from_device(self, sig: str, ent: DevEntry) -> None:
+        """A released device entry worth the host tier's price goes
+        there as a dirty (write-back) host snapshot; the copy's time
+        prices the next."""
+        t0 = time.perf_counter()
+        host_value = _tree_to_host(ent.value)
+        self._tier_bw.observe("transfer", "write", ent.nbytes,
+                              time.perf_counter() - t0)
+        self._mem.put(sig, host_value, ent.nbytes, name=ent.name,
+                      meta=ent.meta, state="dirty")
+
+    @contextlib.contextmanager
+    def device_compute(self, name: str, held=frozenset()):
+        """Frame one node's compute: make room on the device first, then
+        record the node's working set (devtier.py). ``held``: the
+        signatures whose values the running job holds, which releasing
+        would not free. Nothing without the device tier."""
+        if self._dev is None or not self._dev.on():
+            yield
+            return
+        self._dev.make_room(name, held)
+        start = self._dev.start()
+        yield
+        self._dev.record(name, start)
+
+    def device_out_of_memory(self, error: BaseException,
+                             held=frozenset()) -> bool:
+        """After a node's compute raised ``error``: if it ran out of
+        device memory and the device tier had entries that may go
+        (neither pinned nor in ``held``), release them all and say so:
+        the node may run once more."""
+        return (self._dev is not None and self._dev.on()
+                and "RESOURCE_EXHAUSTED" in str(error)
+                and self._dev.release_all(held))
+
+    def release_device(self) -> None:
+        """Let go of every device-tier entry (server shutdown)."""
+        if self._dev is not None:
+            self._dev.clear()
 
     # -- remote tier (write-through / read-through) ------------------------
     def _enqueue_upload(self, sig: str, meta: dict) -> None:
@@ -1093,11 +1197,12 @@ class Store:
         wall-time is included in the returned seconds so realized
         per-node runtimes stay honest.
 
-        With a memory tier, a resident signature short-circuits the
-        whole path: the stored pytree is handed back zero-copy (no
-        ``.npy`` read, no unpickle, no ``meta.json`` touch — the reuse
-        bump stays tier-local), and every successful disk/remote load
-        read-through promotes its value for the next caller.
+        With a device or memory tier, a resident signature
+        short-circuits the whole path: the stored pytree is handed back
+        zero-copy (no ``.npy`` read, no unpickle, no ``meta.json`` touch
+        — the reuse bump stays tier-local), and every successful
+        disk/remote load read-through promotes its value for the next
+        caller.
         """
         with spans.span("store.load") as attrs:
             return self._load(sig, sharding_for_leaf, attrs)
@@ -1105,6 +1210,18 @@ class Store:
     def _load(self, sig: str, sharding_for_leaf, attrs: dict
               ) -> tuple[Any, float]:
         """``load``; ``attrs`` are its span's."""
+        if self._dev is not None:
+            t0 = time.perf_counter()
+            dent = self._dev.get(sig)
+            if dent is not None:
+                value = dent.value
+                if sharding_for_leaf is not None:
+                    value = self._place_leaves(value, sharding_for_leaf)
+                seconds = time.perf_counter() - t0
+                attrs.update(tier="device", bytes=dent.nbytes)
+                self._tier_bw.observe("device", "read", dent.nbytes,
+                                      seconds)
+                return value, seconds
         if self._mem is not None:
             t0 = time.perf_counter()
             ent = self._mem.get(sig)
@@ -1166,22 +1283,28 @@ class Store:
         """Re-place a memory-resident pytree's array leaves onto the
         caller's mesh. Leaf numbering matches the saved manifest (both
         are the pytree flatten order), so ``sharding_for_leaf`` sees the
-        same indices it would on a disk load; non-array leaves and
-        leaves the callback declines (None) pass through untouched."""
+        same indices it would on a disk load; non-array leaves, leaves
+        the callback declines (None) and device leaves already on the
+        requested sharding pass through untouched, so a hit whose leaves
+        are all in place moves nothing and opens no span."""
         leaves, treedef = jax.tree_util.tree_flatten(value)
-        placed = []
-        nbytes = 0
+        moves = {}
+        for i, leaf in enumerate(leaves):
+            if isinstance(leaf, (np.ndarray, jax.Array)):
+                sharding = sharding_for_leaf(
+                    i, tuple(leaf.shape), np.dtype(leaf.dtype))
+                if sharding is not None and not (
+                        isinstance(leaf, jax.Array)
+                        and leaf.sharding.is_equivalent_to(sharding,
+                                                           leaf.ndim)):
+                    moves[i] = sharding
+        if not moves:
+            return value
         with spans.span("store.to_device") as attrs:
-            for i, leaf in enumerate(leaves):
-                if isinstance(leaf, (np.ndarray, jax.Array)):
-                    sharding = sharding_for_leaf(
-                        i, tuple(leaf.shape), np.dtype(leaf.dtype))
-                    if sharding is not None:
-                        leaf = jax.device_put(leaf, sharding)
-                        nbytes += leaf.nbytes
-                placed.append(leaf)
-            attrs["bytes"] = nbytes
-        return jax.tree_util.tree_unflatten(treedef, placed)
+            for i, sharding in moves.items():
+                leaves[i] = jax.device_put(leaves[i], sharding)
+            attrs["bytes"] = sum(leaves[i].nbytes for i in moves)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def _load_once(self, sig: str, sharding_for_leaf
                    ) -> tuple[Any, float, dict]:
@@ -1456,6 +1579,7 @@ class Store:
         """Pin ``sig`` against eviction (shared lease; see ``delete``).
         Non-blocking: returns None when the signature is being computed
         right now (then there is nothing on disk to pin yet anyway).
+        A device-tier entry is pinned there too, against release.
 
         When the entry exists only in the remote tier (a planned LOAD
         that will fetch), the pin extends to a remote TTL pin so no
@@ -1464,24 +1588,31 @@ class Store:
         lock = FileLock(self._lease_path(sig), shared=True)
         if not lock.acquire(blocking=False):
             return None
-        if self.remote is None:
+        unpin = None
+        if self._dev is not None and self._dev.pin(sig):
+            unpin = lambda: self._dev.unpin(sig)  # noqa: E731
+        if self.remote is None and unpin is None:
             return lock
         remote_pin = None
-        if not self.has_local(sig) and self.remote.exists(sig):
+        if (self.remote is not None and not self.has_local(sig)
+                and self.remote.exists(sig)):
             remote_pin = self.remote.acquire_pin(sig)
-        return ReadPin(lock, remote_pin)
+        return ReadPin(lock, remote_pin, unpin)
 
     # -- metadata / management ---------------------------------------------------
     def meta(self, sig: str) -> dict:
-        """Entry metadata: local ``meta.json``, else the memory tier's
-        resident record (write-back entries have no disk copy yet), else
-        the remote commit marker (which carries name/nbytes/benefit
+        """Entry metadata: local ``meta.json``, else the device or memory
+        tier's resident record (write-back entries have no disk copy
+        yet), else the remote commit marker (which carries name/nbytes/benefit
         stats — enough for the planner's load-cost estimate on a
         not-yet-fetched entry)."""
         try:
             with open(os.path.join(self._dir(sig), "meta.json")) as f:
                 return json.load(f)
         except (FileNotFoundError, NotADirectoryError):
+            dent = self._dev.peek(sig) if self._dev is not None else None
+            if dent is not None:
+                return dict(dent.meta)
             if self._mem is not None:
                 ent = self._mem.peek(sig)
                 if ent is not None:
@@ -1527,6 +1658,8 @@ class Store:
                     # nothing to credit to the disk ledger.
                     if self._mem is not None:
                         self._mem.drop(sig)
+                    if self._dev is not None:
+                        self._dev.drop(sig)
                     return 0
                 try:
                     with open(os.path.join(d, "meta.json")) as f:
@@ -1539,6 +1672,8 @@ class Store:
                 self._index_apply(remove=[sig])
             if self._mem is not None:
                 self._mem.drop(sig)
+            if self._dev is not None:
+                self._dev.drop(sig)
         finally:
             if lease_guard is not None:
                 lease_guard.release()
@@ -1705,21 +1840,25 @@ class Store:
         return out
 
     def tier_status(self) -> dict:
-        """Per-tier observability snapshot, in TierStack order (memory →
-        local → remote). Every attached tier reports one **unified
-        record** — ``{name, bytes, budget, entries, leases, hits,
-        misses}`` — plus tier-specific extras (memory: dirty/demotions/
-        spills/offloads; local: ``remote_hits``; remote: ``available``
-        and the transfer stats). ``budget`` is None where the store does
-        not own one (the disk budget lives in the Materializer's
-        ledger). Unattached tiers are None. The server's
-        ``status()["tiers"]`` returns exactly this snapshot — one schema
-        at both layers."""
+        """Per-tier observability snapshot, in TierStack order (device →
+        memory → local → remote). Every attached tier reports one
+        **unified record** — ``{name, bytes, budget, entries, leases,
+        hits, misses}`` — plus tier-specific extras (device: releases/
+        drops/working_set; memory: dirty/demotions/spills/offloads;
+        local: ``remote_hits``; remote: ``available`` and the transfer
+        stats). ``budget`` is None where the store does not own one (the
+        disk budget lives in the Materializer's ledger); the device
+        tier's is the device's own limit. Unattached tiers, and a device
+        tier whose devices report no memory statistics, are None. The
+        server's ``status()["tiers"]`` returns exactly this snapshot — one
+        schema at both layers."""
         entries = self.entries()
         with self._stats_lock:
             stats = {tier: dict(d) for tier, d in self.load_stats.items()}
             remote_hits = self.remote_hits
         status: dict = {
+            "device": (self._dev.status() if self._dev is not None
+                       and self._dev.on() else None),
             "memory": (self._mem.status()
                        if self._mem is not None else None),
             "local": {
@@ -1764,17 +1903,31 @@ class Store:
                          ) -> float:
         """Estimated seconds to load ``nbytes`` — the paper's ``l_i``,
         priced per tier: with a ``sig``, the cheapest tier that can
-        serve it (memory → local → remote, each with its own measured
-        EWMA bandwidth and latency floor). Without one (or for an entry
-        resident nowhere) the local disk tier is priced — the durable
-        default every *write* decision reasons about, and numerically
-        identical to the historical single-number estimate."""
+        serve it (device → memory → local → remote; the device tier
+        prices a handoff, the others their measured EWMA bandwidth and
+        latency floor). Without one (or for an entry resident nowhere)
+        the local disk tier is priced, numerically identical to the
+        historical single-number estimate."""
         tier = "local"
         if sig is not None:
-            if self._mem is not None and self._mem.has(sig):
+            if self._dev is not None and self._dev.has(sig):
+                tier = "device"
+            elif self._mem is not None and self._mem.has(sig):
                 tier = "memory"
             elif self.has_local(sig):
                 tier = "local"
             elif self.remote is not None and self.remote.exists(sig):
                 tier = "remote"
+        return self._tier_bw.est_load_seconds(tier, nbytes)
+
+    def est_reload_seconds(self, value: Any, nbytes: float) -> float:
+        """``l_i`` of a value about to be saved, priced at the tier the
+        save keeps it in. A write-back save of device leaves stays in the
+        device tier, so a later load is a handoff. Any other save is
+        priced at the disk tier: a write-through entry's loader in
+        another process reads the disk, and a write-back host entry sits
+        in the memory tier only until pressure spills it there."""
+        tier = ("device" if self._dev is not None
+                and not isinstance(value, Chunked)
+                and self._dev.would_admit(value) else "local")
         return self._tier_bw.est_load_seconds(tier, nbytes)

@@ -1097,6 +1097,9 @@ class SessionServer:
             self._cv.notify_all()
         self._dispatcher.join(timeout=30.0)
         self._job_pool.shutdown(wait=True)
+        # Device memory goes back to the process: what runs after the
+        # server may need it all.
+        self.store.release_device()
         # Settle the write-through: queued uploads must land before the
         # remote handle (and its lease heartbeat) goes away, or a warm
         # remote tier silently misses this host's last materializations.

@@ -232,7 +232,8 @@ def test_tier_status_unified_schema(tmp_path):
     store.writer_drain()
     store.load("ab12")                              # one memory hit
     status = store.tier_status()
-    assert list(status) == ["memory", "local", "remote"]
+    assert list(status) == ["device", "memory", "local", "remote"]
+    assert status["device"] is None         # write-through: no device tier
     for tier in ("memory", "local", "remote"):
         rec = status[tier]
         assert rec is not None
