@@ -1,37 +1,46 @@
-"""Model FLOPs per token, from a config's widths.
+"""Model FLOPs and per-scope work of the step programs, from the config's
+plain reference module (``reference/<config["reference"]>.py``).
 
-Counted: every matmul of the model as published (2 FLOPs per multiply-
-add), causal attention over the lower triangle only, and the LM head.
-Not counted: the embedding lookup (a gather, whatever the program makes
-of it), norms, activations, the optimizer, and any recomputation.
-Training is forward plus backward, 3x the forward.
+Each reference module counts its own family in one function,
+``scope_work(config, program, batch, seq) -> {scope: (flops, bytes)}``;
+its docstring says what is counted and what is left out. Model FLOPs are
+2 per multiply-add, causal attention over the lower triangle only, with
+no recomputation; training is forward plus backward, 3x the forward. A
+program's FLOPs are the sum over its scopes. A module without
+``scope_work`` is an error, never a default.
 """
 from __future__ import annotations
 
+import cell
 
-def dense_forward(c: dict, seq: int) -> float:
-    d, f = c["hidden_size"], c["intermediate_size"]
-    h, kv = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = d // h
-    layer = (2 * d * h * hd            # q
-             + 2 * 2 * d * kv * hd     # k, v
-             + 2 * h * hd * d          # o
-             + 3 * 2 * d * f           # gate, up, down
-             + 2 * 2 * h * hd * (seq + 1) / 2)   # QK^T and PV, causal mean
-    return c["num_hidden_layers"] * layer + 2 * d * c["vocab_size"]
+TRAIN, EVAL = "helix_train_step", "helix_eval_nll"
 
 
-def forward_per_token(config: dict, seq: int) -> float:
-    if config["family"] == "dense":
-        return dense_forward(config, seq)
-    raise ValueError(f"no FLOP count for family {config['family']!r}")
+def scope_work(config: dict, program: str, batch: int, seq: int) -> dict:
+    """``{scope: (flops, bytes)}`` of one run of ``program``."""
+    mod = cell.reference_module(config)
+    if not callable(getattr(mod, "scope_work", None)):
+        raise TypeError(f"reference module {mod.__name__!r} has no "
+                        f"scope_work")
+    return mod.scope_work(config, program, batch, seq)
+
+
+def program_flops(config: dict, program: str, batch: int, seq: int) -> float:
+    """Model FLOPs of one run of ``program``: the sum over its scopes."""
+    return sum(f for f, _ in scope_work(config, program, batch,
+                                        seq).values())
 
 
 def train_step(config: dict, batch: int, seq: int) -> float:
     """One optimizer step over ``batch`` x ``seq`` tokens."""
-    return 3 * forward_per_token(config, seq) * batch * seq
+    return program_flops(config, TRAIN, batch, seq)
 
 
 def eval_pass(config: dict, batch: int, seq: int) -> float:
     """One forward over ``batch`` x ``seq`` tokens."""
-    return forward_per_token(config, seq) * batch * seq
+    return program_flops(config, EVAL, batch, seq)
+
+
+def forward_per_token(config: dict, seq: int) -> float:
+    """Model FLOPs of one token's forward pass at context ``seq``."""
+    return eval_pass(config, 1, seq) / seq

@@ -8,7 +8,12 @@ usual recipe for fp8 training.
 
 A model module supplies ``param_spec(config)``, ``vocab(config)`` and
 ``forward(config, params, tokens, mm) -> logits``; params are the nested
-dict of :mod:`weights`, read as float32.
+dict of :mod:`weights`, read as float32. It also counts its family's
+work, for ``flops.py`` and the per-scope rooflines: ``scope_work(config,
+program, batch, seq) -> {scope: (flops, bytes)}`` for ``program`` in
+``helix_train_step`` and ``helix_eval_nll``, one entry per
+``jax.named_scope`` the program puts on that family's layers; a
+program's model FLOPs are the sum of its entries.
 """
 from __future__ import annotations
 

@@ -228,6 +228,10 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
         # Cache every program, not only those that take a second to
         # compile, so that a run's set-up compiles nothing after the first.
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        # The trace's scopes are the executable's own op metadata: an entry
+        # compiled from other sources must not stand in for this one.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     e2e, per_layer = manifest_metrics(workload)
     sizes, traffic = c.sizes, Traffic(c.traffic, seed)
     model = cell_lib.reference_module(c.config)
@@ -314,14 +318,17 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
                 result["metrics"][m["name"]] = {"value": values[m["name"]],
                                                 "unit": m["unit"]}
     else:
+        work = {p: flops.scope_work(c.config, p, sizes.batch, sizes.seq_len)
+                for p in PROGRAMS}
         found = devtrace.find(trace_dir)
-        reduced = devtrace.reduce(found, PROGRAMS) if found else None
+        reduced = (devtrace.reduce(found, PROGRAMS,
+                                   {s for w in work.values() for s in w})
+                   if found else None)
         data = {"iterations": done, "trace": reduced,
                 "compiles": tally["compiles"],
-                "flops": {"helix_train_step": flops.train_step(
-                              c.config, sizes.batch, sizes.seq_len),
-                          "helix_eval_nll": flops.eval_pass(
-                              c.config, sizes.batch, sizes.seq_len)},
+                "flops": {p: sum(f for f, _ in w.values())
+                          for p, w in work.items()},
+                "work": work,
                 "peak": (peaks.peak(dev.device_kind) if require_chip
                          else None)}
         readers = load_readers([m["name"] for m in per_layer])

@@ -110,10 +110,12 @@ class Programs:
         def helix_eval_nll(params, toks):
             # One row at a time: a row's float32 logits are 0.76 GB.
             def one(tok):
-                logits = lm.forward(cfg, params, tok[None]).logits[0, :-1]
-                logits = logits.astype(jnp.float32)
-                gold = jnp.take_along_axis(logits, tok[1:, None], -1)[:, 0]
-                return jax.nn.logsumexp(logits, -1) - gold
+                logits = lm.forward(cfg, params, tok[None]).logits
+                with jax.named_scope("loss"):
+                    logits = logits[0, :-1].astype(jnp.float32)
+                    gold = jnp.take_along_axis(logits, tok[1:, None],
+                                               -1)[:, 0]
+                    return jax.nn.logsumexp(logits, -1) - gold
             return jax.lax.map(one, toks)
 
         def helix_state_copy(state):

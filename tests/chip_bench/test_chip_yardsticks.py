@@ -35,7 +35,7 @@ def test_dense_layer_flops_by_hand():
     k_v = 2 * (2 * d * 1024)              # Wk and Wv: 8 heads x 128
     mlp = 3 * 2 * d * f
     attn = 2 * 2 * 16 * 128 * (s + 1) / 2  # causal QK^T and PV
-    assert flops.dense_forward(one, s) == q_o + k_v + mlp + attn
+    assert flops.forward_per_token(one, s) == q_o + k_v + mlp + attn
 
 
 def test_reference_forward_matches_the_program():
