@@ -7,7 +7,17 @@ holds one instance per assigned arch id.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Optional
+
+
+def _tuples(obj) -> None:
+    """Hold every list-valued field of a frozen dataclass as a tuple: a
+    config read from JSON brings lists, and a config must hash."""
+    for f in dataclasses.fields(obj):
+        v = getattr(obj, f.name)
+        if isinstance(v, list):
+            object.__setattr__(obj, f.name, tuple(v))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +39,9 @@ class SSMCfg:
     d_conv: int = 4
     chunk: int = 128              # SSD chunk length
     a_init_range: tuple = (1.0, 16.0)
+
+    def __post_init__(self):
+        _tuples(self)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +103,17 @@ class ArchConfig:
     # under-report FLOPs/bytes by ~layers×accum. Unrolling restores exact
     # counts (slower compile; never used for real runs).
     unroll: bool = False
+
+    def __post_init__(self):
+        # Sub-configs may arrive as mappings (a config read from JSON):
+        # hold each as its dataclass, so that the config hashes and equals
+        # the one built in code.
+        for name, cls in (("moe", MoECfg), ("ssm", SSMCfg),
+                          ("encdec", EncDecCfg)):
+            v = getattr(self, name)
+            if isinstance(v, Mapping):
+                object.__setattr__(self, name, cls(**v))
+        _tuples(self)
 
     @property
     def resolved_head_dim(self) -> int:

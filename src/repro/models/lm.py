@@ -312,7 +312,8 @@ def _ssm_stack(cfg, params, h, positions, cache):
         else:
             p, = xs
             state = None
-        x = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            x = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
         out, new_state = ssd_lib.ssm_block(cfg, cfg.ssm, p["ssm"], x, state)
         h = h + out
         if has_ffn:
