@@ -85,7 +85,9 @@ class ArchConfig:
     # vocab-sharded-friendly implementations (§Perf levers): "onehot" turns
     # the embedding gather / xent gold-gather into fused one-hot matmuls so
     # GSPMD never reshards the (vocab, d) table (XLA's sharded-gather
-    # fallback replicates it).
+    # fallback replicates it). Both embed_impl values take the row gather
+    # where the mesh leaves the vocab axis whole (`lm.vocab_split`): the
+    # one-hot embedding runs only on a vocab-sharded table.
     embed_impl: str = "onehot"    # gather | onehot
     xent_impl: str = "onehot"     # gather | onehot
     moe_impl: str = "einsum"      # einsum | shard_map (expert-TP, explicit)

@@ -16,6 +16,7 @@ family; prefill fills it in one forward.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -23,8 +24,8 @@ import jax.numpy as jnp
 
 from . import layers, moe as moe_lib, ssd as ssd_lib
 from .config import ArchConfig
-from .params import P, init_params
-from ..sharding.activation import constrain, batch_axes
+from .params import P, init_params, param_specs
+from ..sharding.activation import constrain, batch_axes, placement_mesh
 
 
 class LMOut(NamedTuple):
@@ -176,15 +177,67 @@ def _windows_array(cfg: ArchConfig) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def vocab_split(cfg: ArchConfig) -> bool:
+    """Whether the embedding table's vocab axis is split over more than one
+    device: its spec resolved against the mesh the shardings come from
+    (no mesh: whole on one device)."""
+    mesh = placement_mesh()
+    if mesh is None:
+        return False
+    spec = param_specs(P((cfg.vocab_size, cfg.d_model), ("vocab", "embed")),
+                       mesh)
+    return len(spec) > 0 and spec[0] is not None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gather_rows(rows: int, table: jax.Array, tokens: jax.Array
+                 ) -> jax.Array:
+    return table[tokens]
+
+
+def _gather_rows_fwd(rows, table, tokens):
+    return table[tokens], tokens
+
+
+def _gather_rows_bwd(rows, tokens, g):
+    # Duplicate ids sum in float32 and each row rounds once, as the one-hot
+    # matmul's float32 accumulator does; a scatter-add in the table's dtype
+    # would round after every duplicate. Sorting the ids first sums them
+    # over the batch's rows and writes each distinct row once: on a v5e
+    # half the time of a float32 scatter-add into a zero (rows, d) table.
+    d = g.shape[-1]
+    ids = tokens.reshape(-1)
+    n = ids.shape[0]
+    order = jnp.argsort(ids)
+    ids = ids[order]
+    first = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+    seg = jnp.cumsum(first, dtype=jnp.int32) - 1
+    sums = jax.ops.segment_sum(
+        g.reshape(n, d)[order].astype(jnp.float32), seg, num_segments=n,
+        indices_are_sorted=True)
+    # Each segment's id; segments past the last keep the out-of-range id
+    # ``rows`` and are dropped.
+    seg_ids = jnp.full((n,), rows, ids.dtype).at[seg].set(ids)
+    grad = jnp.zeros((rows, d), g.dtype).at[seg_ids].set(
+        sums.astype(g.dtype), mode="drop", unique_indices=True)
+    return grad, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
 def embed_lookup(cfg: ArchConfig, table: jax.Array, tokens: jax.Array
                  ) -> jax.Array:
-    """Embedding lookup. "onehot" expresses the lookup as a one-hot matmul —
-    the one-hot fuses into the dot, and a vocab-sharded table contracts with
-    a psum instead of XLA's replicate-the-table sharded-gather fallback."""
-    if cfg.embed_impl == "onehot":
+    """Embedding lookup: a row gather wherever the table's vocab axis is
+    whole on each device. With the vocab axis split (``vocab_split``),
+    "onehot" expresses the lookup as a one-hot matmul — the one-hot fuses
+    into the dot, and the sharded table contracts with a psum instead of
+    XLA's replicate-the-table sharded-gather fallback."""
+    table = table.astype(jnp.bfloat16)
+    if cfg.embed_impl == "onehot" and vocab_split(cfg):
         oh = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=jnp.bfloat16)
-        return oh @ table.astype(jnp.bfloat16)
-    return table.astype(jnp.bfloat16)[tokens]
+        return oh @ table
+    return _gather_rows(table.shape[0], table, tokens)
 
 
 def _maybe_remat(fn, cfg: ArchConfig):

@@ -47,6 +47,18 @@ def _active_mesh():
     return mesh
 
 
+def placement_mesh():
+    """The mesh the program's shardings come from: the ``with mesh:``
+    mesh, else the one ``jax.set_mesh`` or ``use_abstract_mesh`` entered
+    (``with mesh:`` leaves the abstract mesh empty); None outside both."""
+    mesh = _active_mesh()
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return None
+    return mesh
+
+
 def constrain(x: jax.Array, *axes) -> jax.Array:
     mesh = _active_mesh()
     if mesh is None:
